@@ -189,11 +189,12 @@ _WEIGHT_KEYS = WEIGHT_KEYS  # back-compat alias
 
 def quantize_weights_rtn(params, cfg: ArchConfig, mxcfg: mxlib.MXConfig):
     """Fake-quantize every linear weight along its input axis (axis -2)."""
+    def rtn(w):
+        return mxlib.quantize(w.T, mxcfg, ste=False).T.astype(w.dtype)
+
     def visit(path, leaf):
         name = str(path[-1].key) if hasattr(path[-1], "key") else ""
         if name in _WEIGHT_KEYS and leaf.ndim >= 2:
-            wt = jnp.swapaxes(leaf, -1, -2)
-            wq = mxlib.quantize(wt, mxcfg, ste=False)
-            return jnp.swapaxes(wq, -1, -2).astype(leaf.dtype)
+            return mxlib.map_matrices(rtn, leaf)
         return leaf
     return jax.tree_util.tree_map_with_path(visit, params)

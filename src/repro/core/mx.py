@@ -286,6 +286,23 @@ def decode(codes: jnp.ndarray, scales: jnp.ndarray,
     return vb.reshape(*lead, d)
 
 
+def map_matrices(fn, *xs):
+    """Apply ``fn`` to one trailing (K, N) matrix of ``xs`` at a time.
+
+    For offline whole-model passes over layer- or expert-stacked weights
+    (RTN, packing). Run over a whole (L, K, N) stack at once, the
+    32-blocked (…, K//32, 32) temporaries pad 4x on a TPU, and a stack of
+    a 1B model's FFN weights then overflows the device; one matrix at a
+    time they stay small. ``fn`` returns an array or a pytree of arrays,
+    each with the matrices' leading dims restored."""
+    lead = xs[0].shape[:-2]
+    if not lead:
+        return fn(*xs)
+    flat = tuple(x.reshape((-1,) + x.shape[-2:]) for x in xs)
+    out = jax.lax.map(lambda a: fn(*a), flat)
+    return jax.tree.map(lambda o: o.reshape(lead + o.shape[1:]), out)
+
+
 def packed_nbytes(shape: Sequence[int], cfg: MXConfig | None = None) -> int:
     """Deployable byte count: 4-bit packed codes + 1 byte scale per block.
 

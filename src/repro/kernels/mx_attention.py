@@ -21,11 +21,18 @@ and falls back to the jnp reference off-contract):
                                     are stale and masked)
   window    static int            — sliding-window size (0 = full causal)
 
-Grid: (B, S/BS) with the KV-chunk axis innermost, so the (H, Dh) fp32
-accumulator plus the (H,) running max / normalizer stay resident in VMEM
-across the KV sweep (the GEMM kernels' K-innermost discipline). GQA runs
-natively: q is viewed (kvh, G, Dh) and scores contract against the
-decoded (BS, kvh, Dh) tile per kv-head.
+Grid: (B, S/BS) with the KV-chunk axis innermost, so the fp32 accumulator
+and the running max / normalizer stay resident in VMEM scratch across
+the KV sweep (the GEMM kernels' K-innermost discipline). ``q_pos`` and
+``kv_len`` ride in as scalar-prefetch operands (SMEM), like the paged
+kernels' block table.
+
+Layout inside the kernel (what Mosaic accepts): the wrapper views q
+head-leading as (B, kvh, G, Dh), so GQA is a leading batch axis of the
+score and PV contractions. A decoded KV tile is built *feature-major*:
+the (BS, D) bytes are transposed to (D, BS), whose sublane axis then
+splits into 32-blocks for the E8M0 scales and into (kvh, Dh) heads —
+Mosaic can split the sublane axis of a tile but not its lane axis.
 
 Masking is per *row* (lane): causal ``kp <= q_pos``, fill ``kp < kv_len``
 and window ``kp > q_pos - window`` — identical key selection to
@@ -33,10 +40,9 @@ and window ``kp > q_pos - window`` — identical key selection to
 step with no semantic change. Odd tails (kv_len not a multiple of BS) are
 masked chunks, which are exact no-ops of the online softmax.
 
-VMEM per instance (BS=512, D=4096, mxfp8): codes 2x 2 MiB + scales 2x
-64 KiB + q/acc « 16 MiB. On CPU the kernel runs in interpret mode
-(correctness only); the TPU story is the roofline rows in
-``benchmarks/kernels_bench.py``.
+Off-TPU the kernels run in interpret mode (the CPU tests); on TPU they
+compile through Mosaic (``tests/test_tpu_compile.py`` compiles them at
+TinyLlama widths for a described v5e).
 """
 from __future__ import annotations
 
@@ -47,7 +53,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .mx_quant import MXBLOCK, _decode_tile, _format_consts, _quant_tile
+from .mx_quant import (MXBLOCK, _decode_tile, _format_consts, _quant_tile,
+                       e8m0_to_f32)
 from . import packing
 
 NEG_INF = -1e30
@@ -90,7 +97,8 @@ def _decode_codes(codes, fmt, grid, center):
 
     both exact in f32 (the values ARE f32-representable grid points), so
     this is bit-identical to the LUT decode — pinned by the kernel-vs-
-    oracle tests across every format."""
+    oracle tests across every format. The floor division and modulus by
+    8 are an arithmetic shift and a mask."""
     rel = codes.astype(jnp.int32) - center
     if fmt in ("mxint8", "mxfp8"):
         sign = jnp.where(rel < 0, -1.0, 1.0).astype(jnp.float32)
@@ -98,83 +106,92 @@ def _decode_codes(codes, fmt, grid, center):
         if fmt == "mxint8":
             return sign * k.astype(jnp.float32)
         kf = k.astype(jnp.float32)
-        e = jnp.floor_divide(k - 8, 8) + 1
-        m = jnp.remainder(k - 8, 8).astype(jnp.float32)
+        e = ((k - 8) >> 3) + 1
+        m = ((k - 8) & 7).astype(jnp.float32)
         norm = (1.0 + m / 8.0) * jnp.exp2(e.astype(jnp.float32) - 7.0)
         return sign * jnp.where(k < 8, kf * jnp.float32(2.0 ** -9), norm)
     return _decode_tile(codes, grid, center)
 
 
 def _decode_kv_tile(codes, scales, fmt, grid, center, bits, kvh, dh):
-    """(BS, D*bits/8) codes + (BS, D//32) E8M0 bytes -> (BS, kvh, dh) f32."""
+    """(BS, D*bits/8) codes + (BS, D//32) E8M0 bytes -> (kvh, dh, BS) f32,
+    feature-major (see the module docstring)."""
+    c = codes.astype(jnp.int32).T                           # (D*bits/8, BS)
     if bits == 4:
-        # canonical nibble unpack (pack_codes order: even index in the
-        # low nibble) — pure jnp, so it traces inside the kernel body
-        codes = packing.unpack_codes(codes)
-    vals = _decode_codes(codes, fmt, grid, center)          # (BS, D)
-    s = jnp.exp2(scales.astype(jnp.float32) - 127.0)        # (BS, D//32)
-    bs, d = vals.shape
-    out = (vals.reshape(bs, d // MXBLOCK, MXBLOCK) * s[..., None])
-    return out.reshape(bs, kvh, dh)
+        # pack_codes order: feature 2i in the low nibble of byte i
+        c = jnp.stack([c & 0xF, (c >> 4) & 0xF], axis=1).reshape(
+            -1, c.shape[1])
+    vals = _decode_codes(c, fmt, grid, center)              # (D, BS)
+    s = e8m0_to_f32(scales).T                               # (D//32, BS)
+    d, bs = vals.shape
+    out = vals.reshape(d // MXBLOCK, MXBLOCK, bs) * s[:, None, :]
+    return out.reshape(kvh, dh, bs)
 
 
-def _flash_decode_kernel(q_ref, kc_ref, ks_ref, vc_ref, vs_ref,
-                         pos_ref, len_ref, o_ref, m_ref, l_ref, *,
-                         fmt, bits, window, kvh, dh, n_chunks):
+def _online_softmax_step(s, ok, v, m_sc, l_sc, acc_sc):
+    """One online-softmax update over a KV tile. s (kvh, R, S) scores;
+    ok a mask broadcastable to s; v (kvh, dh, S) feature-major values;
+    m_sc / l_sc (kvh, R, 1) and acc_sc (kvh, R, dh) VMEM scratch. A
+    fully masked tile is an exact no-op."""
+    s = jnp.where(ok, s, NEG_INF)
+    m_prev = m_sc[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+    corr = jnp.exp(m_prev - m_new)
+    l_sc[...] = l_sc[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+    m_sc[...] = m_new
+    acc_sc[...] = acc_sc[...] * corr + jnp.einsum(
+        "krs,kds->krd", p, v, preferred_element_type=jnp.float32)
+
+
+def _softmax_init(m_sc, l_sc, acc_sc):
+    m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+    l_sc[...] = jnp.zeros_like(l_sc)
+    acc_sc[...] = jnp.zeros_like(acc_sc)
+
+
+def _softmax_scratch(kvh, rows, dh):
+    return [pltpu.VMEM((kvh, rows, 1), jnp.float32),
+            pltpu.VMEM((kvh, rows, 1), jnp.float32),
+            pltpu.VMEM((kvh, rows, dh), jnp.float32)]
+
+
+def _flash_decode_kernel(pos_ref, len_ref, q_ref, kc_ref, ks_ref, vc_ref,
+                         vs_ref, o_ref, m_sc, l_sc, acc_sc, *, fmt, bits,
+                         window, kvh, dh, n_chunks):
     grid, _, _, center = _format_consts(fmt)
+    b = pl.program_id(0)
     c = pl.program_id(1)
     bs = kc_ref.shape[1]
 
     @pl.when(c == 0)
     def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        _softmax_init(m_sc, l_sc, acc_sc)
 
-    q = q_ref[0].astype(jnp.float32)                        # (H, Dh)
-    H = q.shape[0]
-    G = H // kvh
-    qg = q.reshape(kvh, G, dh)
+    q = q_ref[0].astype(jnp.float32)                        # (kvh, G, Dh)
     scale = 1.0 / jnp.sqrt(jnp.asarray(dh, jnp.float32))
-
     k = _decode_kv_tile(kc_ref[0], ks_ref[0], fmt, grid, center, bits,
                         kvh, dh)
     v = _decode_kv_tile(vc_ref[0], vs_ref[0], fmt, grid, center, bits,
                         kvh, dh)
-
-    s = jnp.einsum("kgd,skd->kgs", qg, k,
+    s = jnp.einsum("kgd,kds->kgs", q, k,
                    preferred_element_type=jnp.float32) * scale
 
-    kp = (c * bs
-          + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)[0])  # (bs,)
-    qp = pos_ref[0, 0]
-    ok = (kp <= qp) & (kp < len_ref[0, 0])
+    kp = c * bs + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bs), 2)
+    qp = pos_ref[b]
+    ok = (kp <= qp) & (kp < len_ref[b])
     if window:
         ok = ok & (kp > qp - window)
-    okb = ok[None, None, :]                                  # (1, 1, bs)
-    s = jnp.where(okb, s, NEG_INF)
-
-    m_prev = m_ref[0].reshape(kvh, G)
-    l_prev = l_ref[0].reshape(kvh, G)
-    acc_prev = o_ref[0].reshape(kvh, G, dh)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    p = jnp.where(okb, jnp.exp(s - m_new[..., None]), 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    l_new = l_prev * corr + jnp.sum(p, axis=-1)
-    acc = acc_prev * corr[..., None] + jnp.einsum(
-        "kgs,skd->kgd", p, v, preferred_element_type=jnp.float32)
-
-    m_ref[...] = m_new.reshape(1, H)
-    l_ref[...] = l_new.reshape(1, H)
-
-    @pl.when(c < n_chunks - 1)
-    def _stash():
-        o_ref[...] = acc.reshape(1, H, dh)
+    _online_softmax_step(s, ok, v, m_sc, l_sc, acc_sc)
 
     @pl.when(c == n_chunks - 1)
     def _finalize():
-        o_ref[...] = (acc / jnp.maximum(l_new, 1e-30)[..., None]
-                      ).reshape(1, H, dh)
+        o_ref[0] = acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)
+
+
+def _lanes(x, B):
+    """Scalar-or-(B,) int operand -> (B,) int32."""
+    return jnp.broadcast_to(jnp.asarray(x, jnp.int32).reshape(-1), (B,))
 
 
 def mx_flash_decode(q: jnp.ndarray, k_codes: jnp.ndarray,
@@ -197,42 +214,36 @@ def mx_flash_decode(q: jnp.ndarray, k_codes: jnp.ndarray,
     assert H % kvh == 0 and kvh * Dh == D, (q.shape, k_codes.shape)
     assert D % MXBLOCK == 0, (D,)
     assert k_scales.shape == (B, S, D // MXBLOCK), k_scales.shape
+    G = H // kvh
     bs = _pick_chunk(S, bs, explicit=explicit_bs)
     n_chunks = S // bs
-    pos2 = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32).reshape(-1),
-                            (B,)).reshape(B, 1)
-    len2 = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32).reshape(-1),
-                            (B,)).reshape(B, 1)
     kern = functools.partial(_flash_decode_kernel, fmt=fmt, bits=bits,
                              window=window, kvh=kvh, dh=Dh,
                              n_chunks=n_chunks)
     db = k_codes.shape[2]
     ns = D // MXBLOCK
-    out, _, _ = pl.pallas_call(
-        kern,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
         grid=(B, n_chunks),
         in_specs=[
-            pl.BlockSpec((1, H, Dh), lambda i, c: (i, 0, 0)),
-            pl.BlockSpec((1, bs, db), lambda i, c: (i, c, 0)),
-            pl.BlockSpec((1, bs, ns), lambda i, c: (i, c, 0)),
-            pl.BlockSpec((1, bs, db), lambda i, c: (i, c, 0)),
-            pl.BlockSpec((1, bs, ns), lambda i, c: (i, c, 0)),
-            pl.BlockSpec((1, 1), lambda i, c: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, c: (i, 0)),
+            pl.BlockSpec((1, kvh, G, Dh), lambda i, c, *_: (i, 0, 0, 0)),
+            pl.BlockSpec((1, bs, db), lambda i, c, *_: (i, c, 0)),
+            pl.BlockSpec((1, bs, ns), lambda i, c, *_: (i, c, 0)),
+            pl.BlockSpec((1, bs, db), lambda i, c, *_: (i, c, 0)),
+            pl.BlockSpec((1, bs, ns), lambda i, c, *_: (i, c, 0)),
         ],
-        out_specs=(
-            pl.BlockSpec((1, H, Dh), lambda i, c: (i, 0, 0)),
-            pl.BlockSpec((1, H), lambda i, c: (i, 0)),
-            pl.BlockSpec((1, H), lambda i, c: (i, 0)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((B, H, Dh), jnp.float32),
-            jax.ShapeDtypeStruct((B, H), jnp.float32),
-            jax.ShapeDtypeStruct((B, H), jnp.float32),
-        ),
+        out_specs=pl.BlockSpec((1, kvh, G, Dh),
+                               lambda i, c, *_: (i, 0, 0, 0)),
+        scratch_shapes=_softmax_scratch(kvh, G, Dh),
+    )
+    out = pl.pallas_call(
+        kern,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, kvh, G, Dh), jnp.float32),
         interpret=interpret,
-    )(q, k_codes, k_scales, v_codes, v_scales, pos2, len2)
-    return out
+    )(_lanes(q_pos, B), _lanes(kv_len, B), q.reshape(B, kvh, G, Dh),
+      k_codes, k_scales, v_codes, v_scales)
+    return out.reshape(B, H, Dh)
 
 
 # ---------------------------------------------------------------------------
@@ -254,17 +265,12 @@ def mx_flash_decode(q: jnp.ndarray, k_codes: jnp.ndarray,
 # exactly like the contiguous kernel's stale tail.
 
 
-def _flash_decode_paged_kernel(bt_ref, q_ref, kc_ref, ks_ref, vc_ref,
-                               vs_ref, pos_ref, len_ref, o_ref, m_ref,
-                               l_ref, *, fmt, bits, window, kvh, dh,
-                               n_chunks):
+def _flash_decode_paged_kernel(bt_ref, *refs, **kw):
     # bt_ref (the prefetched block table) is consumed by the index maps;
     # the body is position-identical to the contiguous kernel because a
     # page IS chunk c of its lane's logical cache.
     del bt_ref
-    _flash_decode_kernel(q_ref, kc_ref, ks_ref, vc_ref, vs_ref, pos_ref,
-                         len_ref, o_ref, m_ref, l_ref, fmt=fmt, bits=bits,
-                         window=window, kvh=kvh, dh=dh, n_chunks=n_chunks)
+    _flash_decode_kernel(*refs, **kw)
 
 
 def mx_flash_decode_paged(q: jnp.ndarray, k_codes: jnp.ndarray,
@@ -295,43 +301,37 @@ def mx_flash_decode_paged(q: jnp.ndarray, k_codes: jnp.ndarray,
     assert D % MXBLOCK == 0, (D,)
     ns = D // MXBLOCK
     assert k_scales.shape == (N, P, ns), k_scales.shape
-    pos2 = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32).reshape(-1),
-                            (B,)).reshape(B, 1)
-    len2 = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32).reshape(-1),
-                            (B,)).reshape(B, 1)
+    G = H // kvh
     kern = functools.partial(_flash_decode_paged_kernel, fmt=fmt,
                              bits=bits, window=window, kvh=kvh, dh=Dh,
                              n_chunks=maxp)
+
+    def page(i, c, bt, *_):
+        return (bt[i, c], 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=3,
         grid=(B, maxp),
         in_specs=[
-            pl.BlockSpec((1, H, Dh), lambda i, c, bt: (i, 0, 0)),
-            pl.BlockSpec((1, P, db), lambda i, c, bt: (bt[i, c], 0, 0)),
-            pl.BlockSpec((1, P, ns), lambda i, c, bt: (bt[i, c], 0, 0)),
-            pl.BlockSpec((1, P, db), lambda i, c, bt: (bt[i, c], 0, 0)),
-            pl.BlockSpec((1, P, ns), lambda i, c, bt: (bt[i, c], 0, 0)),
-            pl.BlockSpec((1, 1), lambda i, c, bt: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, c, bt: (i, 0)),
+            pl.BlockSpec((1, kvh, G, Dh), lambda i, c, *_: (i, 0, 0, 0)),
+            pl.BlockSpec((1, P, db), page),
+            pl.BlockSpec((1, P, ns), page),
+            pl.BlockSpec((1, P, db), page),
+            pl.BlockSpec((1, P, ns), page),
         ],
-        out_specs=(
-            pl.BlockSpec((1, H, Dh), lambda i, c, bt: (i, 0, 0)),
-            pl.BlockSpec((1, H), lambda i, c, bt: (i, 0)),
-            pl.BlockSpec((1, H), lambda i, c, bt: (i, 0)),
-        ),
+        out_specs=pl.BlockSpec((1, kvh, G, Dh),
+                               lambda i, c, *_: (i, 0, 0, 0)),
+        scratch_shapes=_softmax_scratch(kvh, G, Dh),
     )
-    out, _, _ = pl.pallas_call(
+    out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=(
-            jax.ShapeDtypeStruct((B, H, Dh), jnp.float32),
-            jax.ShapeDtypeStruct((B, H), jnp.float32),
-            jax.ShapeDtypeStruct((B, H), jnp.float32),
-        ),
+        out_shape=jax.ShapeDtypeStruct((B, kvh, G, Dh), jnp.float32),
         interpret=interpret,
-    )(jnp.asarray(block_tables, jnp.int32), q, k_codes, k_scales,
-      v_codes, v_scales, pos2, len2)
-    return out
+    )(jnp.asarray(block_tables, jnp.int32), _lanes(q_pos, B),
+      _lanes(kv_len, B), q.reshape(B, kvh, G, Dh), k_codes, k_scales,
+      v_codes, v_scales)
+    return out.reshape(B, H, Dh)
 
 
 # ---------------------------------------------------------------------------
@@ -360,119 +360,104 @@ def mx_flash_decode_paged(q: jnp.ndarray, k_codes: jnp.ndarray,
 # mid-page prefix-cache resume never double-counts rows the chunk re-fills);
 # steps c >= maxp read kv-block c - maxp of the dense chunk at positions
 # ``start + (c - maxp)*kvb + iota``. Causal / fill / window masks apply to
-# both sources exactly as in ``models.layers.attention``.
+# both sources exactly as in ``models.layers.attention``. Steps whose keys
+# are all masked for the q-block — pages with no committed row, chunk
+# blocks wholly after the q-block — skip their attention work, which is an
+# exact no-op of the online softmax anyway.
+#
+# Queries are laid out head-leading as (B, kvh, C*G, Dh): row r of a
+# q-block is query r // G of the block, group head r % G.
 
 
-def _quant_kv_tile(x, fmt, grid, mids, r_max, center, bits):
+def _quant_kv_tile(x, fmt, grid, mids, r_max, center, bits, kvh, dh):
     """In-kernel MX encode of a dense (bs, D) f32 tile.
 
     Returns (code bytes (bs, D*bits/8) u8, E8M0 scale bytes (bs, D//32)
-    u8, roundtrip values (bs, D) f32). The bytes are bit-identical to
-    ``packing.kv_encode`` (same ``_quant_tile`` snap, same nibble order,
-    same E8M0 bias) and the roundtrip is computed by decoding those very
-    bytes, so attending the roundtrip == writing the bytes to the pool
-    and reading them back."""
+    u8, roundtrip values (kvh, dh, bs) f32 feature-major). The bytes are
+    bit-identical to ``packing.kv_encode`` (same ``_quant_tile`` snap,
+    same nibble order, same E8M0 bias) and the roundtrip is computed by
+    decoding those very bytes, so attending the roundtrip == writing the
+    bytes to the pool and reading them back."""
     bs, d = x.shape
-    xb = x.reshape(bs, d // MXBLOCK, MXBLOCK)
-    codes, scale = _quant_tile(xb, grid, mids, r_max, center)
+    xb = x.T.reshape(d // MXBLOCK, MXBLOCK, bs)
+    codes, scale = _quant_tile(xb, grid, mids, r_max, center, axis=1)
     sbyte = (jnp.round(jnp.log2(scale)).astype(jnp.int32)
              + E8M0_BIAS)                          # == pack_scales_e8m0
-    codes = codes.reshape(bs, d)
     vals = _decode_codes(codes, fmt, grid, center)
-    s = jnp.exp2(sbyte.astype(jnp.float32) - E8M0_BIAS)
-    rt = (vals.reshape(bs, d // MXBLOCK, MXBLOCK) * s[..., None]
-          ).reshape(bs, d)
-    if bits == 4:
-        cb = codes.reshape(bs, d // 2, 2)          # pack_codes nibble order
-        cbytes = (cb[..., 0] | (cb[..., 1] << 4)).astype(jnp.uint8)
-    else:
-        cbytes = codes.astype(jnp.uint8)
-    return cbytes, sbyte.astype(jnp.uint8), rt
+    rt = (vals * e8m0_to_f32(sbyte)[:, None, :]).reshape(kvh, dh, bs)
+    codes = codes.reshape(d, bs)                   # feature-major
+    if bits == 4:                                  # pack_codes nibble order
+        cb = codes.reshape(d // 2, 2, bs)
+        codes = cb[:, 0] | (cb[:, 1] << 4)
+    return codes.T.astype(jnp.uint8), sbyte.T.astype(jnp.uint8), rt
 
 
-def _flash_prefill_kernel(bt_ref, q_ref, kcp_ref, ksp_ref, vcp_ref,
-                          vsp_ref, kd_ref, vd_ref, start_ref, len_ref,
-                          o_ref, m_ref, l_ref, kc_ref, ks_ref, vc_ref,
-                          vs_ref, *, fmt, bits, window, kvh, dh, maxp,
-                          n_cb, qb, kvb, page):
+def _flash_prefill_kernel(bt_ref, start_ref, len_ref, q_ref, kcp_ref,
+                          ksp_ref, vcp_ref, vsp_ref, kd_ref, vd_ref,
+                          o_ref, kc_ref, ks_ref, vc_ref, vs_ref,
+                          m_sc, l_sc, acc_sc, *, fmt, bits, window, kvh,
+                          dh, group, maxp, n_cb, qb, kvb, page):
     del bt_ref          # consumed by the index maps (scalar prefetch)
     grid, mids, r_max, center = _format_consts(fmt)
+    b = pl.program_id(0)
     j = pl.program_id(1)
     c = pl.program_id(2)
     n_kv = maxp + n_cb
 
     @pl.when(c == 0)
     def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        _softmax_init(m_sc, l_sc, acc_sc)
 
-    q = q_ref[0].astype(jnp.float32)               # (qb, H, Dh)
-    H = q.shape[1]
-    G = H // kvh
-    qg = q.reshape(qb, kvh, G, dh)
+    q = q_ref[0].astype(jnp.float32)               # (kvh, qb*G, Dh)
     sm = 1.0 / jnp.sqrt(jnp.asarray(dh, jnp.float32))
-    start = start_ref[0, 0]
-    kl = len_ref[0, 0]
-    qp = (start + j * qb
-          + jax.lax.broadcasted_iota(jnp.int32, (1, qb), 1)[0])   # (qb,)
+    start = start_ref[b]
+    kl = len_ref[b]
+    q0 = start + j * qb                            # first query position
+    qp = q0 + jax.lax.broadcasted_iota(
+        jnp.int32, (1, qb * group, 1), 1) // group  # (1, qb*G, 1)
 
     def _update(k, v, kp, src_ok):
-        # One online-softmax step over an (s, kvh, dh) KV tile at logical
-        # positions kp, with src_ok masking rows the source doesn't own.
-        s = jnp.einsum("qkgd,skd->qkgs", qg, k,
+        # One online-softmax step over a (kvh, dh, s) KV tile at logical
+        # positions kp (1, 1, s), with src_ok masking rows the source
+        # doesn't own.
+        s = jnp.einsum("krd,kds->krs", q, k,
                        preferred_element_type=jnp.float32) * sm
-        ok = src_ok & (kp < kl)
-        okb = ok[None, :] & (kp[None, :] <= qp[:, None])
+        ok = src_ok & (kp < kl) & (kp <= qp)
         if window:
-            okb = okb & (kp[None, :] > qp[:, None] - window)
-        okb = okb[:, None, None, :]                # (qb, 1, 1, s)
-        s = jnp.where(okb, s, NEG_INF)
-        m_prev = m_ref[0].reshape(qb, kvh, G)
-        l_prev = l_ref[0].reshape(qb, kvh, G)
-        acc_prev = o_ref[0].reshape(qb, kvh, G, dh)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.where(okb, jnp.exp(s - m_new[..., None]), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + jnp.sum(p, axis=-1)
-        acc = acc_prev * corr[..., None] + jnp.einsum(
-            "qkgs,skd->qkgd", p, v, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new.reshape(1, qb, H)
-        l_ref[...] = l_new.reshape(1, qb, H)
-        o_ref[...] = acc.reshape(1, qb, H, dh)
+            ok = ok & (kp > qp - window)
+        _online_softmax_step(s, ok, v, m_sc, l_sc, acc_sc)
 
-    @pl.when(c < maxp)
+    @pl.when((c < maxp) & (c * page < start))
     def _prefix_page():
         k = _decode_kv_tile(kcp_ref[0], ksp_ref[0], fmt, grid, center,
                             bits, kvh, dh)
         v = _decode_kv_tile(vcp_ref[0], vsp_ref[0], fmt, grid, center,
                             bits, kvh, dh)
-        kp = (c * page
-              + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)[0])
+        kp = c * page + jax.lax.broadcasted_iota(jnp.int32, (1, 1, page), 2)
         _update(k, v, kp, kp < start)
 
     @pl.when(c >= maxp)
     def _chunk_block():
-        cc = c - maxp
+        k0 = start + (c - maxp) * kvb              # first key position
         kb, ksb, krt = _quant_kv_tile(kd_ref[0].astype(jnp.float32), fmt,
-                                      grid, mids, r_max, center, bits)
+                                      grid, mids, r_max, center, bits,
+                                      kvh, dh)
         vb, vsb, vrt = _quant_kv_tile(vd_ref[0].astype(jnp.float32), fmt,
-                                      grid, mids, r_max, center, bits)
-        kc_ref[...] = kb[None]
-        ks_ref[...] = ksb[None]
-        vc_ref[...] = vb[None]
-        vs_ref[...] = vsb[None]
-        kp = (start + cc * kvb
-              + jax.lax.broadcasted_iota(jnp.int32, (1, kvb), 1)[0])
-        _update(krt.reshape(kvb, kvh, dh), vrt.reshape(kvb, kvh, dh), kp,
-                jnp.full((kvb,), True))
+                                      grid, mids, r_max, center, bits,
+                                      kvh, dh)
+        kc_ref[0] = kb
+        ks_ref[0] = ksb
+        vc_ref[0] = vb
+        vs_ref[0] = vsb
+
+        @pl.when(k0 < q0 + qb)                     # some key is causal
+        def _attend():
+            kp = k0 + jax.lax.broadcasted_iota(jnp.int32, (1, 1, kvb), 2)
+            _update(krt, vrt, kp, True)
 
     @pl.when(c == n_kv - 1)
     def _finalize():
-        l = l_ref[0].reshape(qb, kvh, G)
-        acc = o_ref[0].reshape(qb, kvh, G, dh)
-        o_ref[...] = (acc / jnp.maximum(l, 1e-30)[..., None]
-                      ).reshape(1, qb, H, dh)
+        o_ref[0] = acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)
 
 
 def mx_flash_prefill(q: jnp.ndarray, k_chunk: jnp.ndarray,
@@ -518,80 +503,68 @@ def mx_flash_prefill(q: jnp.ndarray, k_chunk: jnp.ndarray,
     assert k_scales.shape == (N, P, ns), k_scales.shape
     assert k_chunk.shape == (B, C, D), (k_chunk.shape, (B, C, D))
     assert maxp >= 1, "prefill needs at least one table slot per lane"
+    G = H // kvh
     qb = _pick_chunk(C, C if qb is None else qb, explicit=explicit_qb)
     kvb = _pick_chunk(C, C if kvb is None else kvb, explicit=explicit_kvb)
     n_qb = C // qb
     n_cb = C // kvb
-    start2 = jnp.broadcast_to(jnp.asarray(q_start, jnp.int32).reshape(-1),
-                              (B,)).reshape(B, 1)
-    len2 = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32).reshape(-1),
-                            (B,)).reshape(B, 1)
     kern = functools.partial(_flash_prefill_kernel, fmt=fmt, bits=bits,
-                             window=window, kvh=kvh, dh=Dh, maxp=maxp,
-                             n_cb=n_cb, qb=qb, kvb=kvb, page=P)
+                             window=window, kvh=kvh, dh=Dh, group=G,
+                             maxp=maxp, n_cb=n_cb, qb=qb, kvb=kvb, page=P)
+
     # Index-map clamps: pool specs only matter on steps c < maxp (chunk
     # steps clamp to the last table slot — any valid page id, rows unused);
     # chunk specs only matter on steps c >= maxp (pool steps clamp to
     # chunk block 0, unread). The chunk-byte output blocks are fully
     # written on every chunk step, and the last grid step visiting each
     # block is a chunk step, so revisiting is flush-safe.
+    def pool_page(i, j, c, bt, *_):
+        return (bt[i, jnp.minimum(c, maxp - 1)], 0, 0)
+
+    def chunk_block(i, j, c, *_):
+        return (i, jnp.maximum(c - maxp, 0), 0)
+
+    def q_block(i, j, c, *_):
+        return (i, 0, j, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=3,
         grid=(B, n_qb, maxp + n_cb),
         in_specs=[
-            pl.BlockSpec((1, qb, H, Dh), lambda i, j, c, bt: (i, j, 0, 0)),
-            pl.BlockSpec((1, P, db),
-                         lambda i, j, c, bt:
-                         (bt[i, jnp.minimum(c, maxp - 1)], 0, 0)),
-            pl.BlockSpec((1, P, ns),
-                         lambda i, j, c, bt:
-                         (bt[i, jnp.minimum(c, maxp - 1)], 0, 0)),
-            pl.BlockSpec((1, P, db),
-                         lambda i, j, c, bt:
-                         (bt[i, jnp.minimum(c, maxp - 1)], 0, 0)),
-            pl.BlockSpec((1, P, ns),
-                         lambda i, j, c, bt:
-                         (bt[i, jnp.minimum(c, maxp - 1)], 0, 0)),
-            pl.BlockSpec((1, kvb, D),
-                         lambda i, j, c, bt:
-                         (i, jnp.maximum(c - maxp, 0), 0)),
-            pl.BlockSpec((1, kvb, D),
-                         lambda i, j, c, bt:
-                         (i, jnp.maximum(c - maxp, 0), 0)),
-            pl.BlockSpec((1, 1), lambda i, j, c, bt: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, j, c, bt: (i, 0)),
+            pl.BlockSpec((1, kvh, qb * G, Dh), q_block),
+            pl.BlockSpec((1, P, db), pool_page),
+            pl.BlockSpec((1, P, ns), pool_page),
+            pl.BlockSpec((1, P, db), pool_page),
+            pl.BlockSpec((1, P, ns), pool_page),
+            pl.BlockSpec((1, kvb, D), chunk_block),
+            pl.BlockSpec((1, kvb, D), chunk_block),
         ],
         out_specs=(
-            pl.BlockSpec((1, qb, H, Dh), lambda i, j, c, bt: (i, j, 0, 0)),
-            pl.BlockSpec((1, qb, H), lambda i, j, c, bt: (i, j, 0)),
-            pl.BlockSpec((1, qb, H), lambda i, j, c, bt: (i, j, 0)),
-            pl.BlockSpec((1, kvb, db),
-                         lambda i, j, c, bt:
-                         (i, jnp.maximum(c - maxp, 0), 0)),
-            pl.BlockSpec((1, kvb, ns),
-                         lambda i, j, c, bt:
-                         (i, jnp.maximum(c - maxp, 0), 0)),
-            pl.BlockSpec((1, kvb, db),
-                         lambda i, j, c, bt:
-                         (i, jnp.maximum(c - maxp, 0), 0)),
-            pl.BlockSpec((1, kvb, ns),
-                         lambda i, j, c, bt:
-                         (i, jnp.maximum(c - maxp, 0), 0)),
+            pl.BlockSpec((1, kvh, qb * G, Dh), q_block),
+            pl.BlockSpec((1, kvb, db), chunk_block),
+            pl.BlockSpec((1, kvb, ns), chunk_block),
+            pl.BlockSpec((1, kvb, db), chunk_block),
+            pl.BlockSpec((1, kvb, ns), chunk_block),
         ),
+        scratch_shapes=_softmax_scratch(kvh, qb * G, Dh),
     )
-    out, _, _, kc, ks, vc, vs = pl.pallas_call(
+    # (B, C, H, Dh) -> head-leading (B, kvh, C*G, Dh)
+    qh = q.reshape(B, C, kvh, G, Dh).transpose(0, 2, 1, 3, 4).reshape(
+        B, kvh, C * G, Dh)
+    out, kc, ks, vc, vs = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=(
-            jax.ShapeDtypeStruct((B, C, H, Dh), jnp.float32),
-            jax.ShapeDtypeStruct((B, C, H), jnp.float32),
-            jax.ShapeDtypeStruct((B, C, H), jnp.float32),
+            jax.ShapeDtypeStruct((B, kvh, C * G, Dh), jnp.float32),
             jax.ShapeDtypeStruct((B, C, db), jnp.uint8),
             jax.ShapeDtypeStruct((B, C, ns), jnp.uint8),
             jax.ShapeDtypeStruct((B, C, db), jnp.uint8),
             jax.ShapeDtypeStruct((B, C, ns), jnp.uint8),
         ),
         interpret=interpret,
-    )(jnp.asarray(block_tables, jnp.int32), q, k_codes, k_scales,
-      v_codes, v_scales, k_chunk, v_chunk, start2, len2)
+    )(jnp.asarray(block_tables, jnp.int32), _lanes(q_start, B),
+      _lanes(kv_len, B), qh, k_codes, k_scales, v_codes, v_scales,
+      k_chunk, v_chunk)
+    out = out.reshape(B, kvh, C, G, Dh).transpose(0, 2, 1, 3, 4).reshape(
+        B, C, H, Dh)
     return out, kc, ks, vc, vs

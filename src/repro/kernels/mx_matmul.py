@@ -46,12 +46,8 @@ from jax.experimental import pallas as pl
 
 from repro.core import mx as mxlib
 from repro.core import transforms as tfm
-from .hadamard_quant import _rotate_tile
-from .mx_quant import MXBLOCK, _decode_tile, _format_consts, _quant_tile
-
-# backwards-compatible alias (the decode helper moved to mx_quant so every
-# GEMM variant shares it)
-_decode_codes = _decode_tile
+from .mx_quant import (MXBLOCK, _decode_tile, _format_consts, _quant_tile,
+                       e8m0_to_f32)
 
 
 def _pick_blocks(M: int, N: int, K: int, bm: int, bn: int, bk: int):
@@ -129,15 +125,35 @@ def _unpack_tile(wp):
     ``pack_codes`` puts code 2i in the low nibble and 2i+1 in the high
     nibble of byte i (along the contraction axis), so the interleave is a
     sublane-axis stack+reshape — no gather."""
+    wp = wp.astype(jnp.int32)
     lo = wp & 0xF
     hi = (wp >> 4) & 0xF
     bk2, bn = wp.shape
     return jnp.stack([lo, hi], axis=1).reshape(bk2 * 2, bn)
 
 
+def _quant_act_tile(x, ht, grid, mids, r_max, center):
+    """(BM, BK) f32 activations -> their MX roundtrip values (BM, BK).
+
+    The 32-blocks run along the lane axis, which Mosaic cannot reshape,
+    so the tile is transposed to (BK, BM) and blocked along sublanes as
+    (BK//32, 32, BM). ``ht`` (H₃₂ᵀ, or None) applies the T3 rotation to
+    each block first: (x_b · H)ᵀ = Hᵀ · x_bᵀ."""
+    bm, bk = x.shape
+    nb = bk // MXBLOCK
+    xb = x.T.reshape(nb, MXBLOCK, bm)
+    if ht is not None:
+        hb = jnp.broadcast_to(ht, (nb, MXBLOCK, MXBLOCK))
+        xb = jnp.einsum("bij,bjm->bim", hb, xb,
+                        preferred_element_type=jnp.float32)
+    codes, scale = _quant_tile(xb, grid, mids, r_max, center, axis=1)
+    xq = _decode_tile(codes, grid, center) * scale[:, None, :]
+    return xq.reshape(bk, bm).T
+
+
 def _mx_matmul_packed_kernel(*refs, fmt, t3):
     if t3:
-        x_ref, h_ref, wp_ref, ws_ref, out_ref = refs
+        x_ref, ht_ref, wp_ref, ws_ref, out_ref = refs
     else:
         x_ref, wp_ref, ws_ref, out_ref = refs
     grid, mids, r_max, center = _format_consts(fmt)
@@ -147,20 +163,16 @@ def _mx_matmul_packed_kernel(*refs, fmt, t3):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    x = x_ref[...].astype(jnp.float32)            # (BM, BK)
-    bm, bk = x.shape
-    xb = x.reshape(bm, bk // MXBLOCK, MXBLOCK)
-    if t3:  # fused T3 prologue: rotate each 32-block before quantizing
-        xb = _rotate_tile(xb, h_ref[...].astype(jnp.float32))
-    codes, scale = _quant_tile(xb, grid, mids, r_max, center)
-    xq = (_decode_tile(codes, grid, center)
-          * scale[..., None]).reshape(bm, bk)
+    # fused T3 prologue (t3): rotate each 32-block before quantizing
+    xq = _quant_act_tile(x_ref[...].astype(jnp.float32),
+                         ht_ref[...] if t3 else None,
+                         grid, mids, r_max, center)          # (BM, BK)
+    bk = xq.shape[1]
 
-    wc = _unpack_tile(wp_ref[...])                # (BK, BN) uint8 codes
+    wc = _unpack_tile(wp_ref[...])                # (BK, BN) int32 codes
     wvals = _decode_tile(wc, grid, center)
     bn = wc.shape[1]
-    # E8M0 byte -> power-of-two scale: exp2 of the unbiased exponent
-    ws = jnp.exp2(ws_ref[...].astype(jnp.float32) - 127.0)  # (BK//32, BN)
+    ws = e8m0_to_f32(ws_ref[...])                 # (BK//32, BN)
     w = (wvals.reshape(bk // MXBLOCK, MXBLOCK, bn)
          * ws[:, None, :]).reshape(bk, bn)
 
@@ -194,7 +206,7 @@ def mx_matmul_packed(x: jnp.ndarray, w_packed: jnp.ndarray,
     if t3:
         in_specs.append(pl.BlockSpec((MXBLOCK, MXBLOCK),
                                      lambda i, j, k: (0, 0)))
-        args.append(tfm.hadamard_matrix(MXBLOCK, dtype=jnp.float32))
+        args.append(tfm.hadamard_matrix(MXBLOCK, dtype=jnp.float32).T)
     in_specs += [
         pl.BlockSpec((bk // 2, bn), lambda i, j, k: (k, j)),
         pl.BlockSpec((bk // MXBLOCK, bn), lambda i, j, k: (k, j)),

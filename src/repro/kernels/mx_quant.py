@@ -44,19 +44,29 @@ def _decode_tile(codes, grid, center):
     return sign * val
 
 
-def _quant_tile(xb, grid, mids, r_max, center):
-    """xb: (BM, nb, 32) f32 -> (codes int32, scales f32 (BM, nb))."""
-    amax = jnp.max(jnp.abs(xb), axis=-1)
+def e8m0_to_f32(b):
+    """E8M0 scale bytes -> power-of-two f32 scales. Mosaic has no
+    uint8 -> float32 cast, so the byte widens through int32 first."""
+    return jnp.exp2(b.astype(jnp.int32).astype(jnp.float32) - 127.0)
+
+
+def _quant_tile(xb, grid, mids, r_max, center, axis=-1):
+    """xb: (BM, nb, 32) f32 -> (codes int32, scales f32 (BM, nb)).
+
+    ``axis`` names the 32-element block axis. The compiled kernels pass
+    a transposed (nb, 32, R) tile with ``axis=1``: Mosaic cannot split
+    the lane axis into 32-blocks, but it can split the sublane axis."""
+    amax = jnp.max(jnp.abs(xb), axis=axis, keepdims=True)
     safe = jnp.where(amax > 0, amax, 1.0)
     e = jnp.floor(jnp.log2(safe))
     scale = jnp.where(amax > 0, jnp.exp2(e - r_max), 1.0)
-    z = xb / scale[..., None]
+    z = xb / scale
     mag = jnp.abs(z)
     idx = jnp.zeros(z.shape, jnp.int32)
     for m in mids:                      # len(grid)-1 static compares
         idx += (mag >= m).astype(jnp.int32)
     codes = center + jnp.where(z < 0, -idx, idx)
-    return codes, scale
+    return codes, jnp.squeeze(scale, axis)
 
 
 def _mx_quant_kernel(x_ref, codes_ref, scales_ref, *, fmt):

@@ -426,13 +426,14 @@ def _mx_flash_prefill_jit(q, k_chunk, v_chunk, k_codes, k_scales, v_codes,
             f"shapes matching K; block_tables (B, maxp) int32 with "
             f"maxp >= 1; fmt one of {_pk.KV_FMTS}.")
     it = _default_interpret() if interpret is None else interpret
-    C = q.shape[1]
+    C, H, Dh = q.shape[1], q.shape[2], q.shape[3]
+    G = H * Dh // k_chunk.shape[2]
     explicit_qb = qb is not None
     explicit_kvb = kvb is not None
-    if qb is None:
-        qb = C if it else 128
+    if qb is None:      # compiled: ~128 (query, group-head) rows per block
+        qb = C if it else max(8, 128 // G)
     if kvb is None:
-        kvb = C if it else 512
+        kvb = C if it else 128
     return _ma.mx_flash_prefill(q, k_chunk, v_chunk, k_codes, k_scales,
                                 v_codes, v_scales, block_tables, q_start,
                                 kv_len, fmt, window=window, qb=qb,

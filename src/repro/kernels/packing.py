@@ -85,23 +85,29 @@ def pack_weight(w: jnp.ndarray, fmt: str = "mxfp4"):
     """
     _check_packable(fmt)
     cfg = mxlib.MXConfig(fmt=fmt, block_size=32)
-    wt = jnp.swapaxes(w, -1, -2)                 # (*lead, N, K)
-    if wt.shape[-1] % cfg.block_size != 0:
-        raise ValueError(f"contraction dim {wt.shape[-1]} not divisible by "
+    if w.shape[-2] % cfg.block_size != 0:
+        raise ValueError(f"contraction dim {w.shape[-2]} not divisible by "
                          f"block size {cfg.block_size}")
-    codes_t, scales_t = mxlib.encode(wt, cfg)    # blocked along K
-    packed_t = pack_codes(codes_t)               # (*lead, N, K//2)
-    return {"codes_packed": jnp.swapaxes(packed_t, -1, -2),
-            "scales_e8m0": jnp.swapaxes(pack_scales_e8m0(scales_t), -1, -2),
+
+    def pack(m):                                  # (K, N) -> packed bytes
+        codes_t, scales_t = mxlib.encode(m.T, cfg)   # blocked along K
+        return (pack_codes(codes_t).T,               # (K//2, N)
+                pack_scales_e8m0(scales_t).T)        # (K//32, N)
+
+    codes, scales = mxlib.map_matrices(pack, jnp.asarray(w))
+    return {"codes_packed": codes, "scales_e8m0": scales,
             "fmt": fmt, "shape": tuple(w.shape)}
 
 
 def unpack_weight(bundle, dtype=jnp.float32) -> jnp.ndarray:
     cfg = mxlib.MXConfig(fmt=bundle["fmt"], block_size=32)
-    codes_t = unpack_codes(jnp.swapaxes(bundle["codes_packed"], -1, -2))
-    scales_t = jnp.swapaxes(bundle["scales_e8m0"], -1, -2)
-    out_t = mxlib.decode(codes_t, unpack_scales_e8m0(scales_t), cfg, dtype)
-    return jnp.swapaxes(out_t, -1, -2)
+
+    def unpack(codes, scales):                    # packed bytes -> (K, N)
+        return mxlib.decode(unpack_codes(codes.T),
+                            unpack_scales_e8m0(scales.T), cfg, dtype).T
+
+    return mxlib.map_matrices(unpack, jnp.asarray(bundle["codes_packed"]),
+                              jnp.asarray(bundle["scales_e8m0"]))
 
 
 def packed_bundle_nbytes(bundle) -> int:

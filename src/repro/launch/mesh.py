@@ -12,14 +12,9 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh with explicit Auto axis types where the installed
-    jax exposes them (older releases have no jax.sharding.AxisType and
-    default to auto sharding anyway)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """jax.make_mesh with explicit Auto axis types."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -147,6 +147,9 @@ def main():
     import jax
     import jax.numpy as jnp
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from repro import configs
     from repro.core import ptq
     from repro.data import synthetic

@@ -77,6 +77,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.engine import Engine, Request
 from repro.serving.faults import FaultInjector
 from repro.serving.policy import (RequestState, SchedulingPolicy, ShedError,
@@ -433,8 +434,10 @@ class Server:
         """Graceful drain (module docstring step by step)."""
         self.draining = True
         if self._server is not None:
+            # close() stops the listener at once. Its wait_closed() is not
+            # awaited: it also waits for every open connection, so it
+            # would hold the drain past drain_timeout_s.
             self._server.close()
-            await self._server.wait_closed()
         loop = asyncio.get_running_loop()
         deadline = loop.time() + self.config.drain_timeout_s
         cancelled_stragglers = False
@@ -838,6 +841,7 @@ def main(argv=None) -> int:
     ap.add_argument("--max-len", type=int, default=128)
     ap.add_argument("--drain-timeout-s", type=float, default=30.0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     eng = demo_engine(max_queue_depth=args.max_queue_depth,
                       admit_token_budget=args.admit_token_budget,
                       deadline_ms=args.deadline_ms,

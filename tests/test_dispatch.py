@@ -6,6 +6,7 @@ backend='fused' must reproduce reference-engine logits."""
 import dataclasses
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -208,9 +209,9 @@ def _float_avals_of_size(fn, args, size, skip=("pallas_call",)):
                     found.append((eqn.primitive.name, aval))
             for p in eqn.params.values():
                 for sub in (p if isinstance(p, (tuple, list)) else (p,)):
-                    if isinstance(sub, jax.core.ClosedJaxpr):
+                    if isinstance(sub, jax.extend.core.ClosedJaxpr):
                         visit(sub.jaxpr)
-                    elif isinstance(sub, jax.core.Jaxpr):
+                    elif isinstance(sub, jax.extend.core.Jaxpr):
                         visit(sub)
 
     visit(jax.make_jaxpr(fn)(*args).jaxpr)

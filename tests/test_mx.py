@@ -78,3 +78,18 @@ def test_packed_nbytes():
     cfg = mxlib.MXConfig(fmt="mxfp4", block_size=32)
     # 4-bit codes: n/2 bytes; scales: n/32 bytes
     assert mxlib.packed_nbytes((64, 64), cfg) == 64 * 64 // 2 + 64 * 64 // 32
+
+
+@pytest.mark.parametrize("shape", [(64, 48), (3, 64, 48), (2, 3, 64, 48)])
+def test_rtn_one_matrix_at_a_time_matches_whole_stack(shape):
+    """RTN walks a stacked weight one (K, N) matrix at a time
+    (map_matrices); the values equal quantizing the whole stack at once."""
+    from repro.core import gptq
+    cfg = mxlib.MXConfig(fmt="mxfp4")
+    w = jax.random.normal(jax.random.PRNGKey(3), shape) * 3
+    got = gptq.quantize_weights_rtn({"blocks": {"wd": w}}, None,
+                                    cfg)["blocks"]["wd"]
+    whole = jnp.swapaxes(mxlib.quantize(jnp.swapaxes(w, -1, -2), cfg,
+                                        ste=False), -1, -2)
+    assert got.shape == w.shape and got.dtype == w.dtype
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(whole))
