@@ -123,7 +123,7 @@ def main():
     tracer = Tracer() if args.trace else None
     metrics = MetricsRegistry() if args.metrics else None
     if metrics is not None:          # kernel-dispatch hooks (ops.py)
-        ops.instrument(metrics, tracer)
+        ops.instrument(metrics)
 
     if args.artifact:
         eng = Engine.from_artifact(args.artifact, batch_size=args.batch,

@@ -4,20 +4,18 @@
 for validation); on TPU backends it defaults to False (compiled Mosaic).
 
 Dispatch instrumentation (``docs/observability.md``): after
-:func:`instrument`, every public wrapper records per-op call counts and
-cumulative host-side dispatch time into a ``repro.obs.MetricsRegistry``
-(``kernel_dispatch_calls_total`` / ``kernel_dispatch_seconds_total``,
-labeled by op), and the fused-vs-ref dispatch decisions made one level
-up in ``core.quantize`` land in ``quant_dispatch_total{op,path}``. Calls
-made *inside* an enclosing ``jax.jit`` trace execute once per compile,
-not once per step — they are labeled ``traced="true"`` so compile-time
-inlines and real dispatches never sum into each other. Uninstrumented
-(the default), the wrappers add a single ``is None`` check per call.
+:func:`instrument`, every public wrapper counts its calls per op into a
+``repro.obs.MetricsRegistry`` (``kernel_dispatch_calls_total``), and the
+fused-vs-ref dispatch decisions made one level up in ``core.quantize``
+land in ``quant_dispatch_total{op,path}``. Calls made *inside* an
+enclosing ``jax.jit`` trace execute once per compile, not once per step
+— they are labeled ``traced="true"`` so compile-time inlines and real
+dispatches never sum into each other. Uninstrumented (the default), the
+wrappers add a single ``is None`` check per call.
 """
 from __future__ import annotations
 
 import functools
-import time
 
 import jax
 import jax.numpy as jnp
@@ -38,60 +36,38 @@ def _default_interpret() -> bool:
 # Dispatch instrumentation
 # ----------------------------------------------------------------------
 
-_instr = None       # (registry, tracer) when instrumented
+_registry = None  # the MetricsRegistry when instrumented
 
 
-def instrument(registry, tracer=None) -> None:
-    """Start recording kernel-dispatch metrics into ``registry`` (a
-    ``repro.obs.MetricsRegistry``); optionally also emit a
-    ``dispatch:<op>`` span per python-level call when a
-    ``repro.obs.Tracer`` is given. Global (module-level) — one
+def instrument(registry) -> None:
+    """Start counting kernel dispatches into ``registry`` (a
+    ``repro.obs.MetricsRegistry``). Global (module-level) — one
     instrumentation target at a time; :func:`uninstrument` stops."""
-    global _instr
-    _instr = (registry, tracer)
+    global _registry
+    _registry = registry
 
 
 def uninstrument() -> None:
-    global _instr
-    _instr = None
+    global _registry
+    _registry = None
 
 
 def _is_traced(*xs) -> bool:
     return any(isinstance(x, jax.core.Tracer) for x in xs)
 
 
-def _record(op: str, dt: float, traced: bool) -> None:
-    registry, _ = _instr
-    labels = {"op": op, "traced": "true" if traced else "false"}
-    registry.counter(
-        "kernel_dispatch_calls_total", labels,
-        help="public kernel-wrapper invocations (traced=true rows ran "
-             "inside an enclosing jit trace: once per compile, not per "
-             "step)").inc()
-    registry.counter(
-        "kernel_dispatch_seconds_total", labels, unit="s",
-        help="cumulative host-side dispatch wall time (async device "
-             "work excluded; under interpret mode this is ~the actual "
-             "kernel time)").inc(dt)
-
-
 def _dispatch(op: str, fn, *args, **kwargs):
-    """Call ``fn`` (the jitted implementation), timing the host-side
-    dispatch when instrumented. The timer spans trace+dispatch only —
-    device execution is asynchronous and deliberately NOT waited on (no
-    host sync is ever added to a serving hot loop by instrumentation)."""
-    ins = _instr
-    if ins is None:
-        return fn(*args, **kwargs)
-    traced = _is_traced(*args)
-    _, tracer = ins
-    t0 = time.perf_counter()
-    out = fn(*args, **kwargs)
-    dt = time.perf_counter() - t0
-    _record(op, dt, traced)
-    if tracer is not None and not traced:
-        tracer.complete(f"dispatch:{op}", t0, t0 + dt, cat="kernel")
-    return out
+    """Call ``fn`` (the jitted implementation), counting the call when
+    instrumented."""
+    registry = _registry
+    if registry is not None:
+        traced = "true" if _is_traced(*args) else "false"
+        registry.counter(
+            "kernel_dispatch_calls_total", {"op": op, "traced": traced},
+            help="public kernel-wrapper invocations (traced=true rows "
+                 "ran inside an enclosing jit trace: once per compile, "
+                 "not per step)").inc()
+    return fn(*args, **kwargs)
 
 
 def record_quant_path(op: str, path: str, role: str = "") -> None:
@@ -99,10 +75,10 @@ def record_quant_path(op: str, path: str, role: str = "") -> None:
     decision (``quant_dispatch_total{op, path, role}``). No-op unless
     :func:`instrument` is active. Runs at trace time for calls inside a
     jit — counts are per *compiled call site*, not per step."""
-    ins = _instr
-    if ins is None:
+    registry = _registry
+    if registry is None:
         return
-    ins[0].counter(
+    registry.counter(
         "quant_dispatch_total", {"op": op, "path": path, "role": role},
         help="qlinear/qeinsum execution-path decisions (per traced "
              "call site)").inc()

@@ -177,7 +177,7 @@ def main():
     tracer = Tracer() if args.trace else None
     metrics = MetricsRegistry() if args.metrics else None
     if metrics is not None:          # kernel-dispatch hooks (ops.py)
-        ops.instrument(metrics, tracer)
+        ops.instrument(metrics)
 
     if args.artifact:
         t0 = time.time()

@@ -54,9 +54,12 @@ def kv_write_slice(cache, new: jnp.ndarray, start):
 # ``block_tables[b, t // P]``, row ``t % P`` (see ``packing.PagedKV`` and
 # ``docs/paged-kv.md``). The engine guarantees writable pages are private
 # to their lane (shared prefix pages are read-only), so the scatters below
-# never race across lanes.
+# never race across lanes. The two quantizing writers run under the
+# ``kv_write`` name scope, which a profile's ops carry in their op_name
+# metadata; it covers the encode and the scatter, which XLA may fuse.
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("kv_write")
 def kv_write_token_paged(pool: PagedKV, new: jnp.ndarray,
                          pages: jnp.ndarray, offs: jnp.ndarray) -> PagedKV:
     """Scatter one token per lane into a layer-sliced page pool.
@@ -72,6 +75,7 @@ def kv_write_token_paged(pool: PagedKV, new: jnp.ndarray,
                    pool.fmt, pool.dtype)
 
 
+@jax.named_scope("kv_write")
 def kv_write_chunk_paged(pool: PagedKV, new: jnp.ndarray,
                          block_tables: jnp.ndarray, start) -> PagedKV:
     """Write a C-token chunk at absolute positions start..start+C-1

@@ -1,6 +1,6 @@
 """Serving telemetry: metrics registry + request-lifecycle tracing.
 
-Two small, dependency-free primitives that every serving-stack layer
+Two small primitives that every serving-stack layer
 reports through (see ``docs/observability.md``):
 
 :mod:`repro.obs.metrics`
@@ -16,7 +16,9 @@ reports through (see ``docs/observability.md``):
     wall-clock intervals onto per-thread (and per-request) track
     buffers, exported as Chrome ``trace_event`` JSON
     (``Tracer.export(path)``) that opens directly in Perfetto /
-    ``chrome://tracing``.
+    ``chrome://tracing``. Each span is also a
+    ``jax.profiler.TraceAnnotation``, so a profiler trace of the same
+    run shows it on the host plane beside the device's operations.
 
 The serving engine always carries a registry (counter updates cost the
 same as the plain Python attributes they replaced); tracing is opt-in

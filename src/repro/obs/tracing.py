@@ -1,4 +1,5 @@
-"""Span tracer exporting Chrome ``trace_event`` JSON.
+"""Span tracer exporting Chrome ``trace_event`` JSON, and naming the
+same spans on the profiler's host trace.
 
 ``Tracer.span("decode_step", ...)`` context managers record wall-clock
 intervals (``time.perf_counter`` — monotonic) onto *track buffers*:
@@ -16,13 +17,21 @@ Event vocabulary (the subset of the trace-event format we emit):
   ``compile:decode``), from :meth:`Tracer.instant`.
 * ``ph: "M"`` — track-name metadata, synthesized at export.
 
+Each :meth:`Tracer.span` also enters a ``jax.profiler.TraceAnnotation``
+of the same name and args, so while a profiler session is recording
+(``jax.profiler.trace``) the span lands on the calling thread's line of
+the host plane, on the profiler's clock, nested as the ``with`` blocks
+are. :meth:`Tracer.complete` and :meth:`Tracer.instant` are retroactive
+or zero-length and go to the JSON buffer only.
+
 Spans on one track follow stack discipline (a span entered inside
 another ends before it) — :func:`validate_trace` checks exactly that,
 and is what the schema test and the CI smoke step run against an
 exported file.
 
-Overhead: recording one span is two ``perf_counter`` calls and one
-list append; nothing is flushed or synced until :meth:`export`. When no
+Overhead: recording one span is two ``perf_counter`` calls, one list
+append and an annotation that records nothing unless a profiler session
+is on; nothing is flushed or synced until :meth:`export`. When no
 tracer is installed the serving engine skips even that (``None`` check,
 no context manager is created).
 """
@@ -33,26 +42,32 @@ import threading
 import time
 from typing import Dict, List, Optional, Union
 
+from jax.profiler import TraceAnnotation
+
 __all__ = ["Tracer", "validate_trace"]
 
 
 class _SpanCtx:
-    """Context manager for one complete ('X') event."""
+    """Context manager for one complete ('X') event and its profiler
+    annotation."""
 
-    __slots__ = ("tracer", "name", "tid", "cat", "args", "t0")
+    __slots__ = ("tracer", "name", "tid", "cat", "args", "t0", "ann")
 
     def __init__(self, tracer: "Tracer", name: str, tid: int, cat: str,
                  args: Optional[dict]):
         self.tracer, self.name, self.tid = tracer, name, tid
         self.cat, self.args = cat, args
         self.t0 = 0.0
+        self.ann = TraceAnnotation(name, **(args or {}))
 
     def __enter__(self) -> "_SpanCtx":
+        self.ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
         t1 = time.perf_counter()
+        self.ann.__exit__(*exc)
         ev = {"ph": "X", "name": self.name, "cat": self.cat,
               "ts": self.tracer._us(self.t0),
               "dur": round((t1 - self.t0) * 1e6, 3),
@@ -107,7 +122,9 @@ class Tracer:
         """``with tracer.span("decode_step", batch=4): ...`` records a
         complete event covering the block. ``track=None`` uses the
         calling thread's track; a string names a logical track (created
-        on first use). Keyword args land in the event's ``args``."""
+        on first use). Keyword args land in the event's ``args``. The
+        profiler annotation of the block takes the same name and args
+        on the calling thread, whatever the track."""
         return _SpanCtx(self, name, self._tid(track), cat, args or None)
 
     def complete(self, name: str, t0: float, t1: float,

@@ -45,7 +45,8 @@ share/export it, else a private one is created) — :meth:`Engine.stats`
 is a view over it, including TTFT/TPOT latency histograms. Request
 lifecycle and engine-step spans are recorded when a ``repro.obs.Tracer``
 is passed (``Engine(tracer=...)``) and exported as Chrome trace-event
-JSON; with no tracer the hot loop records nothing.
+JSON; the engine-step spans also land on a running profiler's host
+trace. With no tracer the hot loop records no span.
 
 Clocks: *intervals* (TTFT/TPOT, throughput, span timestamps) are always
 measured with ``time.perf_counter()`` (monotonic — wall clock can step
@@ -362,7 +363,8 @@ class Engine:
                     "useful_decode_tokens", "prefill_chunk_steps",
                     "prefill_batched_steps", "prefill_lane_steps",
                     "prefix_hit_tokens", "blocks_evicted",
-                    "spec_proposed_tokens", "spec_accepted_tokens")
+                    "spec_proposed_tokens", "spec_accepted_tokens",
+                    "admit_seconds")
 
     def __init__(self, params, cfg: ArchConfig, qm: QuantMode,
                  batch_size: int = 4, max_len: int = 256,
@@ -598,6 +600,11 @@ class Engine:
         self._c_evicted = reg.counter(
             "serving_blocks_evicted_total",
             help="cached prefix pages reclaimed by LRU eviction")
+        self._c_admit_s = reg.counter(
+            "serving_admit_seconds_total", unit="s",
+            help="host seconds in the admission phase of continuous "
+                 "steps (prefix match, page allocation, prefill, first "
+                 "token); no lane decodes meanwhile")
         self._g_blocks_in_use = reg.gauge(
             "serving_blocks_in_use", unit="pages",
             help="pages referenced by live block tables")
@@ -1530,7 +1537,8 @@ class Engine:
         with self._span("merge", slot=slot):
             self._cache = self._merge(self._cache, self._slot_cache,
                                       jnp.int32(slot))
-        row = np.asarray(logits)[0]
+        with self._span("logits_fetch", slot=slot):
+            row = np.asarray(logits)[0]
         tok = self._first_token(req, row)
         return sb, tok, bool(np.isfinite(row).all())
 
@@ -1545,15 +1553,16 @@ class Engine:
         index would produce — including after a preemption-resume,
         where the emission index restarts at ``len(req._gen)``."""
         sp = req.sampling
-        if sp is None or sp.greedy:
-            return int(row.argmax())
-        return int(np.asarray(self._sample_tokens(
-            jnp.asarray(row[None]),
-            jnp.asarray([sp.temperature], jnp.float32),
-            jnp.asarray([sp.top_k], jnp.int32),
-            jnp.asarray([sp.top_p], jnp.float32),
-            jnp.asarray([sp.seed], jnp.uint32),
-            jnp.asarray([len(req._gen)], jnp.int32)))[0])
+        with self._span("sample_first"):
+            if sp is None or sp.greedy:
+                return int(row.argmax())
+            return int(np.asarray(self._sample_tokens(
+                jnp.asarray(row[None]),
+                jnp.asarray([sp.temperature], jnp.float32),
+                jnp.asarray([sp.top_k], jnp.int32),
+                jnp.asarray([sp.top_p], jnp.float32),
+                jnp.asarray([sp.seed], jnp.uint32),
+                jnp.asarray([len(req._gen)], jnp.int32)))[0])
 
     def _samp_vectors(self, reqs: List[Optional[Request]],
                       steps: List[int]) -> tuple:
@@ -1659,7 +1668,8 @@ class Engine:
         A preempted request re-admits with prompt+emitted tokens
         (``_effective_prompt``): its original prompt's registered pages
         are prefix-cache hits, so the retry re-prefills only the tail."""
-        plan = self._admit_paged_prep(slot, req)
+        with self._span("admit_prep", slot=slot):
+            plan = self._admit_paged_prep(slot, req)
         if plan is None:
             return None
         return self._prefill_plan_serial(plan)
@@ -1763,7 +1773,9 @@ class Engine:
             self._c_chunk_steps.inc()
             self._c_prefill_lane_steps.inc()
             self._h_prefill_batch.observe(1)
-        return self._admit_paged_finish(plan, np.asarray(logits)[0])
+        with self._span("logits_fetch", slot=slot):
+            row = np.asarray(logits)[0]
+        return self._admit_paged_finish(plan, row)
 
     def _admit_paged_finish(self, plan: dict, row: np.ndarray) -> tuple:
         """Post-prefill bookkeeping of a paged admission: register the
@@ -1917,8 +1929,9 @@ class Engine:
                     stop = True
                     break
                 t_a0 = time.perf_counter()
-                plan = self._admit_paged_prep(i, req,
-                                              in_flight=bool(plans))
+                with self._span("admit_prep", slot=i):
+                    plan = self._admit_paged_prep(i, req,
+                                                  in_flight=bool(plans))
                 while plan is None and self.policy.preemption:
                     # page pressure: same victim/retry dance as serial
                     lane = pick_victim(self._victim_lanes(),
@@ -1926,8 +1939,9 @@ class Engine:
                     if lane is None:
                         break
                     self._preempt(lane, done, "page pressure")
-                    plan = self._admit_paged_prep(i, req,
-                                                  in_flight=bool(plans))
+                    with self._span("admit_prep", slot=i):
+                        plan = self._admit_paged_prep(
+                            i, req, in_flight=bool(plans))
                 if plan is None:
                     self._queue.push_front(req)
                     stop = True
@@ -2004,7 +2018,8 @@ class Engine:
                 # one host fetch per lane, after every dispatch is in
                 # flight — the sync cost is paid once per admission,
                 # exactly like the serial path's trailing fetch
-                row = np.asarray(lane_logits[p["slot"]])[p["slot"]]
+                with self._span("logits_fetch", slot=p["slot"]):
+                    row = np.asarray(lane_logits[p["slot"]])[p["slot"]]
                 res = self._admit_paged_finish(p, row)
                 self._record_admission(p["req"], p["t0"], t_a1, res[2])
                 self._post_admission(p["slot"], p["req"], res, True,
@@ -2047,6 +2062,7 @@ class Engine:
         # contiguous layout, whose admission runs in a single-lane
         # scratch cache) keeps the serial path bit-identical to the
         # pre-batching engine. ---
+        t_admit = time.perf_counter()
         knob = (max(1, self.policy.max_prefill_lanes_per_step)
                 if paged else 1)
         if knob > 1:
@@ -2093,6 +2109,7 @@ class Engine:
                 if blocked:
                     break
         self._admit_cursor = (self._admit_cursor + 1) % self.B
+        self._c_admit_s.inc(time.perf_counter() - t_admit)
 
         live = [i for i in range(self.B) if self._slots[i] is not None]
         if not live:
@@ -2129,35 +2146,38 @@ class Engine:
             # burst so enforcement granularity stays bounded (deadline-
             # free traffic keeps the full burst and its single sync)
             burst = min(burst, max(1, self.policy.deadline_burst_cap))
-        cur = np.zeros(self.B, np.int32)
-        pos = np.zeros(self.B, np.int32)
-        for i in live:
-            cur[i] = self._slots[i].toks[-1]
-            pos[i] = self._slots[i].pos
-        # the greedy closures are dispatched untouched whenever every
-        # live lane is greedy, so greedy traffic (and its compile
-        # counts) is bit-identical to an engine without sampling
-        sampled = any(self._slots[i].req.sampling is not None
-                      and not self._slots[i].req.sampling.greedy
-                      for i in live)
-        self._count_decode_compile(
-            self.B, ("vector-paged" if paged else "vector") +
-                    ("-sampled" if sampled else ""))
-        # committed onto the canonical sharding so the burst's first step
-        # shares one jit signature with the steady-state steps (whose
-        # cur/pos are the previous step's committed outputs)
-        cur_d = self._commit(jnp.asarray(cur))
-        pos_d = self._commit(jnp.asarray(pos))
-        tables_d = self._tables_committed() if paged else None
-        if sampled:
-            # steps[i] = the lane's next emission index: sl.toks already
-            # includes the admission token (emission 0), so index =
-            # len(toks). Idle lanes get greedy no-op args.
-            temps_d, tks_d, tps_d, seeds_d, steps_d = self._samp_vectors(
-                [self._slots[i].req if self._slots[i] is not None else None
-                 for i in range(self.B)],
-                [len(self._slots[i].toks) if self._slots[i] is not None
-                 else 0 for i in range(self.B)])
+        with self._span("decode_prep", lanes=len(live)):
+            cur = np.zeros(self.B, np.int32)
+            pos = np.zeros(self.B, np.int32)
+            for i in live:
+                cur[i] = self._slots[i].toks[-1]
+                pos[i] = self._slots[i].pos
+            # the greedy closures are dispatched untouched whenever every
+            # live lane is greedy, so greedy traffic (and its compile
+            # counts) is bit-identical to an engine without sampling
+            sampled = any(self._slots[i].req.sampling is not None
+                          and not self._slots[i].req.sampling.greedy
+                          for i in live)
+            self._count_decode_compile(
+                self.B, ("vector-paged" if paged else "vector") +
+                        ("-sampled" if sampled else ""))
+            # committed onto the canonical sharding so the burst's first
+            # step shares one jit signature with the steady-state steps
+            # (whose cur/pos are the previous step's committed outputs)
+            cur_d = self._commit(jnp.asarray(cur))
+            pos_d = self._commit(jnp.asarray(pos))
+            tables_d = self._tables_committed() if paged else None
+            if sampled:
+                # steps[i] = the lane's next emission index: sl.toks
+                # already includes the admission token (emission 0), so
+                # index = len(toks). Idle lanes get greedy no-op args.
+                temps_d, tks_d, tps_d, seeds_d, steps_d = \
+                    self._samp_vectors(
+                        [self._slots[i].req if self._slots[i] is not None
+                         else None for i in range(self.B)],
+                        [len(self._slots[i].toks)
+                         if self._slots[i] is not None else 0
+                         for i in range(self.B)])
         toks_dev = []
         oks_dev = []
         with self._span("decode_burst", steps=burst, lanes=len(live)):
@@ -2200,45 +2220,46 @@ class Engine:
             with self._span("host_sync", steps=burst):
                 host = np.asarray(jnp.stack(toks_dev, axis=1))  # 1 sync
                 okh = np.asarray(jnp.stack(oks_dev, axis=1))
-        for step in range(burst):
-            for i in live:
-                sl = self._slots[i]
-                if sl is None:
-                    continue
-                if not okh[i, step]:
-                    # per-lane failure isolation: only the poisoned
-                    # lane's request fails; its private cache rows are
-                    # garbage now but nothing shared was written (decode
-                    # writes land past the registered prefix pages) and
-                    # the lane's next admission overwrites them
-                    req = sl.req
-                    self._c_nan.inc()
-                    if (self.tracer is not None
-                            and req.trace_track is not None):
-                        self.tracer.instant("nan_guard",
-                                            track=req.trace_track,
-                                            cat="request", lane=i,
-                                            step=step)
-                    self._slots[i] = None
-                    if paged:
-                        self._release_paged(i)
-                    self._finish(req, sl.toks,
-                                 state=RequestState.FAILED,
-                                 error=f"non-finite logits in lane {i} "
-                                       f"at decode position {sl.pos}")
-                    done.append(req)
-                    continue
-                tok = int(host[i, step])
-                sl.toks.append(tok)
-                sl.pos += 1
-                sl.remaining -= 1
-                self._emit(sl.req, tok)
-                if sl.remaining == 0 or tok == self.eos_id:
-                    self._finish(sl.req, sl.toks)
-                    done.append(sl.req)
-                    self._slots[i] = None
-                    if paged:
-                        self._release_paged(i)
+        with self._span("emit", steps=burst, lanes=len(live)):
+            for step in range(burst):
+                for i in live:
+                    sl = self._slots[i]
+                    if sl is None:
+                        continue
+                    if not okh[i, step]:
+                        # per-lane failure isolation: only the poisoned
+                        # lane's request fails; its private cache rows are
+                        # garbage now but nothing shared was written (decode
+                        # writes land past the registered prefix pages) and
+                        # the lane's next admission overwrites them
+                        req = sl.req
+                        self._c_nan.inc()
+                        if (self.tracer is not None
+                                and req.trace_track is not None):
+                            self.tracer.instant("nan_guard",
+                                                track=req.trace_track,
+                                                cat="request", lane=i,
+                                                step=step)
+                        self._slots[i] = None
+                        if paged:
+                            self._release_paged(i)
+                        self._finish(req, sl.toks,
+                                     state=RequestState.FAILED,
+                                     error=f"non-finite logits in lane {i} "
+                                           f"at decode position {sl.pos}")
+                        done.append(req)
+                        continue
+                    tok = int(host[i, step])
+                    sl.toks.append(tok)
+                    sl.pos += 1
+                    sl.remaining -= 1
+                    self._emit(sl.req, tok)
+                    if sl.remaining == 0 or tok == self.eos_id:
+                        self._finish(sl.req, sl.toks)
+                        done.append(sl.req)
+                        self._slots[i] = None
+                        if paged:
+                            self._release_paged(i)
         self._expire_running(done, paged)
         return done
 
@@ -2388,7 +2409,8 @@ class Engine:
                 "prefix_hit_tokens": self.prefix_hit_tokens,
                 "blocks_evicted": int(self._c_evicted.value),
                 "spec_proposed_tokens": int(self._c_spec_proposed.value),
-                "spec_accepted_tokens": int(self._c_spec_accepted.value)}
+                "spec_accepted_tokens": int(self._c_spec_accepted.value),
+                "admit_seconds": self._c_admit_s.value}
 
     def reset_stats(self) -> None:
         """Start a new stats *window*: ``stats()['window']`` counts from
@@ -2429,7 +2451,9 @@ class Engine:
         produced a token which made it into a request's output — the
         wave scheduler burns slot-steps on requests shorter than their
         wave; the continuous scheduler only idles lanes when the queue
-        runs dry.
+        runs dry. ``admit_seconds`` is the host time continuous steps
+        spent admitting (seconds, windowed like the counts): every lane
+        waits for it.
 
         Paged-layout counters (zero under 'contiguous'):
         ``prefix_hit_tokens`` — prompt tokens served from cached prefix
@@ -2503,6 +2527,7 @@ class Engine:
                                   else 0),
                 "blocks_evicted": (self._alloc.evicted if self._alloc
                                    else 0),
+                "admit_seconds": cum["admit_seconds"],
                 "ttft_p50": ttft["p50"], "ttft_p99": ttft["p99"],
                 "tpot_p50": tpot["p50"], "tpot_p99": tpot["p99"],
                 "submitted": int(self._c_submitted.value),

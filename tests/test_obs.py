@@ -233,9 +233,10 @@ class TestEngineIntegration:
                     "prefill_chunk_steps", "prefill_compiles",
                     "prefill_chunk_compiles", "decode_compiles",
                     "prefix_hit_tokens", "blocks_in_use",
-                    "blocks_evicted", "kv_cache"):
+                    "blocks_evicted", "kv_cache", "admit_seconds"):
             assert key in st, key
         assert st["admitted"] == len(done) == 4
+        assert st["admit_seconds"] > 0
         # legacy attribute views stay equal to the registry-backed stats
         assert eng.admitted == st["admitted"]
         assert eng.decode_steps == st["decode_steps"]
@@ -252,6 +253,7 @@ class TestEngineIntegration:
         eng.reset_stats()
         st2 = eng.stats()
         assert st2["window"]["admitted"] == 0
+        assert st2["window"]["admit_seconds"] == 0
         assert st2["admitted"] == st["admitted"]      # cumulative kept
 
     def test_trace_has_lifecycle_and_step_spans(self, served, tmp_path):
@@ -278,3 +280,133 @@ class TestEngineIntegration:
         text = eng.metrics.render_prometheus()
         assert "serving_requests_admitted_total 4" in text
         assert "serving_ttft_seconds_count 4" in text
+
+
+# ---------------------------------------------------------------------
+# engine spans on the profiler's trace; the K/V write's device scope
+# ---------------------------------------------------------------------
+
+def _paged_engine(tracer):
+    import jax
+    from repro.configs.base import ArchConfig
+    from repro.core.quantize import QuantMode
+    from repro.models import api
+    from repro.serving.engine import Engine
+
+    cfg = ArchConfig(name="obs-paged", family="dense", n_layers=2,
+                     d_model=64, n_heads=4, n_kv_heads=2, d_ff=128,
+                     vocab_size=128, attn_chunk=16)
+    params = api.init(jax.random.PRNGKey(0), cfg)
+    return Engine(params, cfg, QuantMode.off(), batch_size=2, max_len=64,
+                  scheduler="continuous", kv_layout="paged", page_size=32,
+                  kv_cache="mxfp8", tracer=tracer)
+
+
+def _paged_requests(n=3):
+    from repro.serving.engine import Request
+    rng = np.random.default_rng(1)
+    return [Request(prompt=rng.integers(0, 128, 5 + 7 * i)
+                    .astype(np.int32), max_new=4) for i in range(n)]
+
+
+def _host_events(trace_dir):
+    """Every event of the profile's host planes: name -> [(start, end,
+    stats)]."""
+    import pathlib
+
+    from jax.profiler import ProfileData
+    path = sorted(pathlib.Path(trace_dir).rglob("*.xplane.pb"))[-1]
+    out = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if not plane.name.startswith("/host"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                out.setdefault(ev.name, []).append(
+                    (ev.start_ns, ev.end_ns, dict(ev.stats)))
+    return out
+
+
+def test_engine_spans_land_on_the_profilers_host_plane(tmp_path):
+    import jax
+    eng = _paged_engine(Tracer())
+    eng.generate(_paged_requests(1))          # compile outside the trace
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level, opts.host_tracer_level = 0, 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        done = eng.generate(_paged_requests())
+    finally:
+        jax.profiler.stop_trace()
+    assert all(r.state.value == "finished" for r in done)
+    host = _host_events(tmp_path)
+    for name in ("engine_step", "admit", "admit_prep", "logits_fetch",
+                 "sample_first", "decode_prep", "decode_burst",
+                 "decode_step", "host_sync", "emit"):
+        assert host.get(name), name
+    # the spans' args ride along as the annotations' stats
+    assert all("slot" in st for _s, _e, st in host["admit_prep"])
+    assert all({"steps", "lanes"} <= set(st)
+               for _s, _e, st in host["decode_burst"])
+    assert all("steps" in st for _s, _e, st in host["emit"])
+    # nested as they ran (batched admission preps its lanes before the
+    # one admit span that prefills them)
+    def inside(child, parent):
+        return all(any(ps <= cs and ce <= pe for ps, pe, _ in host[parent])
+                   for cs, ce, _ in host[child])
+    for child, parent in (("admit_prep", "engine_step"),
+                          ("logits_fetch", "admit"),
+                          ("sample_first", "admit"),
+                          ("admit", "engine_step"),
+                          ("decode_step", "decode_burst"),
+                          ("emit", "engine_step")):
+        assert inside(child, parent), (child, parent)
+    # retroactive request spans stay in the JSON export only
+    assert "request" not in host and "first_token" not in host
+
+
+@pytest.mark.parametrize("traced", [False, True])
+def test_annotations_only_with_a_tracer(monkeypatch, traced):
+    from repro.obs import tracing
+    entered = []
+
+    class Counting(tracing.TraceAnnotation):
+        def __enter__(self):
+            entered.append(1)
+            return super().__enter__()
+    monkeypatch.setattr(tracing, "TraceAnnotation", Counting)
+    eng = _paged_engine(Tracer() if traced else None)
+    done = eng.generate(_paged_requests())
+    assert all(r.state.value == "finished" for r in done)
+    assert bool(entered) == traced
+
+
+@pytest.mark.parametrize("writer", ["token", "chunk"])
+def test_kv_write_scope_names_every_op_of_the_write(writer):
+    """The MX encode and the pool scatter both carry ``kv_write`` in
+    their op_name metadata, whatever XLA fuses them into."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from repro.kernels.packing import PagedKV
+    from repro.models import layers
+
+    pool = PagedKV.zeros((4, 16, 64), "mxfp8")
+    if writer == "token":
+        new = jnp.ones((2, 1, 64), jnp.float32)
+        args = (pool, new, jnp.asarray([1, 2], jnp.int32),
+                jnp.asarray([0, 5], jnp.int32))
+        fn = layers.kv_write_token_paged
+    else:
+        new = jnp.ones((2, 8, 64), jnp.float32)
+        args = (pool, new, jnp.asarray([[1, 2], [3, 0]], jnp.int32),
+                jnp.int32(4))
+        fn = layers.kv_write_chunk_paged
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    # the program's own ops (argument names and the scatters' combiner
+    # bodies carry bare op_names)
+    names = re.findall(r'op_name="(jit\([^"]*)"', text)
+    assert any(n.endswith("scatter") for n in names)
+    assert any("searchsorted" in n for n in names)       # the encode
+    assert all("/kv_write/" in n for n in names), names
