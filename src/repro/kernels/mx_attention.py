@@ -84,36 +84,7 @@ def _pick_chunk(S: int, bs: int, explicit: bool = False) -> int:
     return max(bs, 1)
 
 
-def _decode_codes(codes, fmt, grid, center):
-    """Symmetric code -> float value. The 4-bit grids decode with the
-    shared 8-compare loop (``_decode_tile``); the 8-bit grids would cost
-    ~128 VPU compares per element that way, so they decode
-    *arithmetically* — their half-grids are closed-form:
-
-      int8:      v(k) = k                      (k = |code - center|)
-      fp8 e4m3:  v(k) = k * 2^-9                      for k < 8
-                 v(k) = (1 + m/8) * 2^(e-7),  e = (k-8)//8 + 1,
-                                              m = (k-8) % 8   otherwise
-
-    both exact in f32 (the values ARE f32-representable grid points), so
-    this is bit-identical to the LUT decode — pinned by the kernel-vs-
-    oracle tests across every format. The floor division and modulus by
-    8 are an arithmetic shift and a mask."""
-    rel = codes.astype(jnp.int32) - center
-    if fmt in ("mxint8", "mxfp8"):
-        sign = jnp.where(rel < 0, -1.0, 1.0).astype(jnp.float32)
-        k = jnp.abs(rel)
-        if fmt == "mxint8":
-            return sign * k.astype(jnp.float32)
-        kf = k.astype(jnp.float32)
-        e = ((k - 8) >> 3) + 1
-        m = ((k - 8) & 7).astype(jnp.float32)
-        norm = (1.0 + m / 8.0) * jnp.exp2(e.astype(jnp.float32) - 7.0)
-        return sign * jnp.where(k < 8, kf * jnp.float32(2.0 ** -9), norm)
-    return _decode_tile(codes, grid, center)
-
-
-def _decode_kv_tile(codes, scales, fmt, grid, center, bits, kvh, dh):
+def _decode_kv_tile(codes, scales, fmt, bits, kvh, dh):
     """(BS, D*bits/8) codes + (BS, D//32) E8M0 bytes -> (kvh, dh, BS) f32,
     feature-major (see the module docstring)."""
     c = codes.astype(jnp.int32).T                           # (D*bits/8, BS)
@@ -121,7 +92,7 @@ def _decode_kv_tile(codes, scales, fmt, grid, center, bits, kvh, dh):
         # pack_codes order: feature 2i in the low nibble of byte i
         c = jnp.stack([c & 0xF, (c >> 4) & 0xF], axis=1).reshape(
             -1, c.shape[1])
-    vals = _decode_codes(c, fmt, grid, center)              # (D, BS)
+    vals = _decode_tile(c, fmt)                             # (D, BS)
     s = e8m0_to_f32(scales).T                               # (D//32, BS)
     d, bs = vals.shape
     out = vals.reshape(d // MXBLOCK, MXBLOCK, bs) * s[:, None, :]
@@ -159,7 +130,6 @@ def _softmax_scratch(kvh, rows, dh):
 def _flash_decode_kernel(pos_ref, len_ref, q_ref, kc_ref, ks_ref, vc_ref,
                          vs_ref, o_ref, m_sc, l_sc, acc_sc, *, fmt, bits,
                          window, kvh, dh, n_chunks):
-    grid, _, _, center = _format_consts(fmt)
     b = pl.program_id(0)
     c = pl.program_id(1)
     bs = kc_ref.shape[1]
@@ -170,10 +140,8 @@ def _flash_decode_kernel(pos_ref, len_ref, q_ref, kc_ref, ks_ref, vc_ref,
 
     q = q_ref[0].astype(jnp.float32)                        # (kvh, G, Dh)
     scale = 1.0 / jnp.sqrt(jnp.asarray(dh, jnp.float32))
-    k = _decode_kv_tile(kc_ref[0], ks_ref[0], fmt, grid, center, bits,
-                        kvh, dh)
-    v = _decode_kv_tile(vc_ref[0], vs_ref[0], fmt, grid, center, bits,
-                        kvh, dh)
+    k = _decode_kv_tile(kc_ref[0], ks_ref[0], fmt, bits, kvh, dh)
+    v = _decode_kv_tile(vc_ref[0], vs_ref[0], fmt, bits, kvh, dh)
     s = jnp.einsum("kgd,kds->kgs", q, k,
                    preferred_element_type=jnp.float32) * scale
 
@@ -383,7 +351,7 @@ def _quant_kv_tile(x, fmt, grid, mids, r_max, center, bits, kvh, dh):
     codes, scale = _quant_tile(xb, grid, mids, r_max, center, axis=1)
     sbyte = (jnp.round(jnp.log2(scale)).astype(jnp.int32)
              + E8M0_BIAS)                          # == pack_scales_e8m0
-    vals = _decode_codes(codes, fmt, grid, center)
+    vals = _decode_tile(codes, fmt)
     rt = (vals * e8m0_to_f32(sbyte)[:, None, :]).reshape(kvh, dh, bs)
     codes = codes.reshape(d, bs)                   # feature-major
     if bits == 4:                                  # pack_codes nibble order
@@ -429,10 +397,8 @@ def _flash_prefill_kernel(bt_ref, start_ref, len_ref, q_ref, kcp_ref,
 
     @pl.when((c < maxp) & (c * page < start))
     def _prefix_page():
-        k = _decode_kv_tile(kcp_ref[0], ksp_ref[0], fmt, grid, center,
-                            bits, kvh, dh)
-        v = _decode_kv_tile(vcp_ref[0], vsp_ref[0], fmt, grid, center,
-                            bits, kvh, dh)
+        k = _decode_kv_tile(kcp_ref[0], ksp_ref[0], fmt, bits, kvh, dh)
+        v = _decode_kv_tile(vcp_ref[0], vsp_ref[0], fmt, bits, kvh, dh)
         kp = c * page + jax.lax.broadcasted_iota(jnp.int32, (1, 1, page), 2)
         _update(k, v, kp, kp < start)
 

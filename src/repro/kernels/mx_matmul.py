@@ -5,7 +5,10 @@
 — the deployment hot-spot after LATMiX folding: activations arrive bf16,
 are MX-quantized on the fly (per-row 32-blocks along K), the weight tile is
 decoded from its stored codes + power-of-two column scales, and the MXU
-accumulates fp32 over the K grid axis.
+accumulates fp32 over the K grid axis. The packed kernel hands the MXU
+bf16 operands: an MX value is a mantissa of at most 3 bits times a power
+of two, which bf16 holds exactly, and every product of two is exact in
+fp32, so only the order of the fp32 sums differs from an f32 x f32 dot.
 
 Two weight layouts:
 
@@ -15,9 +18,9 @@ Two weight layouts:
                                two 4-bit codes per byte ((K//2, N) uint8)
                                + E8M0 scale bytes ((K//32, N) uint8),
                                decoded inside the kernel tile. No dense fp
-                               weight is ever materialized, and the weight
-                               VMEM/HBM traffic is half the uint8-per-code
-                               layout (9 bits/param total vs 17).
+                               weight is ever materialized; the weight
+                               stores 4.25 bits/param (4 of code, 8/32 of
+                               scale) against 8.25 for one code per byte.
 
 ``mx_matmul_packed(t3=True)`` additionally fuses the online T3
 block-Hadamard into the activation-quantize prologue (the ``ffn_down``
@@ -41,13 +44,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import mx as mxlib
 from repro.core import transforms as tfm
-from .mx_quant import (MXBLOCK, _decode_tile, _format_consts, _quant_tile,
-                       e8m0_to_f32)
+from .mx_quant import (MXBLOCK, _decode_tile, _f32_bits, _format_consts,
+                       _quant_tile, e8m0_to_f32)
 
 
 def _pick_blocks(M: int, N: int, K: int, bm: int, bn: int, bk: int):
@@ -76,12 +78,11 @@ def _mx_matmul_kernel(x_ref, wc_ref, ws_ref, out_ref, *, fmt, n_k):
     bm, bk = x.shape
     xb = x.reshape(bm, bk // MXBLOCK, MXBLOCK)
     codes, scale = _quant_tile(xb, grid, mids, r_max, center)
-    xq = (_decode_tile(codes, grid, center)
-          * scale[..., None]).reshape(bm, bk)
+    xq = (_decode_tile(codes, fmt) * scale[..., None]).reshape(bm, bk)
 
     wc = wc_ref[...]                              # (BK, BN) uint8
     ws = ws_ref[...]                              # (BK//32, BN) f32
-    wvals = _decode_tile(wc, grid, center)
+    wvals = _decode_tile(wc, fmt)
     bn = wc.shape[1]
     w = (wvals.reshape(bk // MXBLOCK, MXBLOCK, bn)
          * ws[:, None, :]).reshape(bk, bn)
@@ -119,20 +120,33 @@ def mx_matmul(x: jnp.ndarray, w_codes: jnp.ndarray, w_scales: jnp.ndarray,
 # Packed-native layout: nibble-packed codes + E8M0 scale bytes in, fp out
 # ---------------------------------------------------------------------------
 
-def _unpack_tile(wp):
-    """(BK//2, BN) nibble-packed uint8 -> (BK, BN) uint8 codes.
+def _weight_tile_bf16(wp, ws, fmt):
+    """(BK//2, BN) nibble-packed codes + (BK//32, BN) E8M0 bytes -> the
+    (BK, BN) bf16 weight tile, with no interleave of the codes.
 
-    ``pack_codes`` puts code 2i in the low nibble and 2i+1 in the high
-    nibble of byte i (along the contraction axis), so the interleave is a
-    sublane-axis stack+reshape — no gather."""
+    A 32-bit word of a bf16 tile holds two adjacent rows, the even one in
+    its low half (``pltpu.bitcast`` reads an int32 tile so), and
+    ``pack_codes`` puts code 2i in the low nibble of byte i. So each byte
+    decodes, in place, into the word that holds its two rows: the value
+    times its scale in f32, rounded to bf16 by its top 16 bits (+0x8000:
+    to nearest, as ``astype`` rounds; an MX value times a power of two is
+    exact, and one a few ulps off by ``exp2`` is never halfway). Stacking
+    the unpacked codes into K order instead took over half the kernel's
+    time on a v5e."""
     wp = wp.astype(jnp.int32)
-    lo = wp & 0xF
-    hi = (wp >> 4) & 0xF
     bk2, bn = wp.shape
-    return jnp.stack([lo, hi], axis=1).reshape(bk2 * 2, bn)
+    s = e8m0_to_f32(ws)[:, None, :]              # 16 bytes per 32-block
+
+    def rounded_bits(codes):
+        v = _decode_tile(codes, fmt).reshape(bk2 // 16, 16, bn) * s
+        return _f32_bits(v.reshape(bk2, bn)) + 0x8000
+
+    lo, hi = rounded_bits(wp & 0xF), rounded_bits(wp >> 4)
+    return pltpu.bitcast(((lo >> 16) & 0xFFFF) | (hi & -0x10000),
+                         jnp.bfloat16)
 
 
-def _quant_act_tile(x, ht, grid, mids, r_max, center):
+def _quant_act_tile(x, ht, fmt):
     """(BM, BK) f32 activations -> their MX roundtrip values (BM, BK).
 
     The 32-blocks run along the lane axis, which Mosaic cannot reshape,
@@ -146,8 +160,9 @@ def _quant_act_tile(x, ht, grid, mids, r_max, center):
         hb = jnp.broadcast_to(ht, (nb, MXBLOCK, MXBLOCK))
         xb = jnp.einsum("bij,bjm->bim", hb, xb,
                         preferred_element_type=jnp.float32)
+    grid, mids, r_max, center = _format_consts(fmt)
     codes, scale = _quant_tile(xb, grid, mids, r_max, center, axis=1)
-    xq = _decode_tile(codes, grid, center) * scale[:, None, :]
+    xq = _decode_tile(codes, fmt) * scale[:, None, :]
     return xq.reshape(bk, bm).T
 
 
@@ -156,7 +171,6 @@ def _mx_matmul_packed_kernel(*refs, fmt, t3):
         x_ref, ht_ref, wp_ref, ws_ref, out_ref = refs
     else:
         x_ref, wp_ref, ws_ref, out_ref = refs
-    grid, mids, r_max, center = _format_consts(fmt)
     k_idx = pl.program_id(2)
 
     @pl.when(k_idx == 0)
@@ -165,18 +179,11 @@ def _mx_matmul_packed_kernel(*refs, fmt, t3):
 
     # fused T3 prologue (t3): rotate each 32-block before quantizing
     xq = _quant_act_tile(x_ref[...].astype(jnp.float32),
-                         ht_ref[...] if t3 else None,
-                         grid, mids, r_max, center)          # (BM, BK)
-    bk = xq.shape[1]
-
-    wc = _unpack_tile(wp_ref[...])                # (BK, BN) int32 codes
-    wvals = _decode_tile(wc, grid, center)
-    bn = wc.shape[1]
-    ws = e8m0_to_f32(ws_ref[...])                 # (BK//32, BN)
-    w = (wvals.reshape(bk // MXBLOCK, MXBLOCK, bn)
-         * ws[:, None, :]).reshape(bk, bn)
-
-    out_ref[...] += jnp.dot(xq, w, preferred_element_type=jnp.float32)
+                         ht_ref[...] if t3 else None, fmt)   # (BM, BK)
+    w = _weight_tile_bf16(wp_ref[...], ws_ref[...], fmt)     # (BK, BN)
+    # MX values are exact in bf16 (see the module docstring)
+    out_ref[...] += jnp.dot(xq.astype(jnp.bfloat16), w,
+                            preferred_element_type=jnp.float32)
 
 
 def mx_matmul_packed(x: jnp.ndarray, w_packed: jnp.ndarray,

@@ -22,6 +22,7 @@ from jax.experimental import pallas as pl
 from repro.core import mx as mxlib
 
 MXBLOCK = 32
+_SIGN_BIT = -(1 << 31)          # the f32 sign bit, as an int32
 
 
 def _format_consts(fmt: str):
@@ -31,17 +32,46 @@ def _format_consts(fmt: str):
     return grid, mids, el.r_max, len(el.grid) - 1  # center code
 
 
-def _decode_tile(codes, grid, center):
-    """uint8 symmetric code -> float value, via static compares (the grid
-    has <= 8 magnitudes; Pallas forbids captured jnp LUT constants).
-    Shared by every GEMM kernel variant that dequantizes codes in-tile."""
-    rel = codes.astype(jnp.int32) - center
-    sign = jnp.where(rel < 0, -1.0, 1.0).astype(jnp.float32)
+def _f32_bits(x):
+    return jax.lax.bitcast_convert_type(x, jnp.int32)
+
+
+def _bits_f32(b):
+    return jax.lax.bitcast_convert_type(b, jnp.float32)
+
+
+def _decode_tile(codes, fmt):
+    """Symmetric code -> float value, in closed form (Pallas forbids
+    captured jnp LUT constants). With k = |code - center|, each half-grid
+    is a few integer ops on an f32 bit pattern:
+
+      int4, int8:  v = k
+      fp4 e2m1:    f = the f32 whose exponent's low bits and top mantissa
+                   bit are k's three bits, scaled by 1/2 (0.5, 0.75, 1,
+                   1.5, 2, 3, 4, 6); v = min(f, 2f - 1) turns the two
+                   subnormals (k = 0, 1) into 0, 0.5 and leaves the rest
+      fp8 e4m3:    v = k * 2^-9 (subnormals, k < 8), else the f32 whose
+                   exponent's low bits and top three mantissa bits are
+                   k - 8 (bias moved from 7 to 127)
+
+    and the sign is the f32 sign bit of code - center, OR-ed in. Every
+    value is exact in f32, so this is bit-identical to the grid lookup
+    (pinned exhaustively in tests). Shared by every kernel that
+    dequantizes codes in-tile."""
+    el = mxlib.FORMATS[fmt]
+    rel = codes.astype(jnp.int32) - (len(el.grid) - 1)
+    if el.name in ("int4", "int8"):
+        return rel.astype(jnp.float32)
     k = jnp.abs(rel)
-    val = jnp.zeros(codes.shape, jnp.float32)
-    for i, g in enumerate(grid):                  # static python loop
-        val += jnp.where(k == i, float(g), 0.0)
-    return sign * val
+    if el.name == "fp4_e2m1":
+        f = _bits_f32((k << 22) + (126 << 23))
+        mag = jnp.minimum(f, 2.0 * f - 1.0)
+    elif el.name == "fp8_e4m3":
+        sub = k.astype(jnp.float32) * jnp.float32(2.0 ** -9)
+        mag = jnp.where(k < 8, sub, _bits_f32((k << 20) + (120 << 23)))
+    else:
+        raise NotImplementedError(f"no in-kernel decode for {fmt!r}")
+    return _bits_f32(_f32_bits(mag) | (rel & _SIGN_BIT))
 
 
 def e8m0_to_f32(b):

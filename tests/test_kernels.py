@@ -141,3 +141,117 @@ def test_gemm_bf16_inputs():
     yr = ops.mx_matmul_ref(x.astype(jnp.float32), wc, ws)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr),
                                atol=2e-2, rtol=2e-2)
+
+
+@pytest.mark.parametrize("fmt", FMTS + ["mxfp8", "mxint8"])
+def test_decode_tile_is_the_grid_lookup_bit_for_bit(fmt):
+    """The closed-form in-kernel decode equals the format's grid, sign
+    included, for every code the format has."""
+    from repro.core import mx as mxlib
+    from repro.kernels.mx_quant import _decode_tile
+    full = mxlib.FORMATS[fmt].full_grid().astype(np.float32)
+    codes = jnp.arange(len(full), dtype=jnp.int32)
+    got = np.asarray(_decode_tile(codes, fmt))
+    np.testing.assert_array_equal(got.view(np.uint32), full.view(np.uint32))
+
+
+@pytest.mark.parametrize("fmt", FMTS)
+def test_scale_application_matches_unpack_scales_e8m0(fmt):
+    """Every decoded code times every E8M0 byte the packer can write
+    (255 is the format's NaN) equals the packing module's dequant."""
+    from repro.core import mx as mxlib
+    from repro.kernels import packing
+    from repro.kernels.mx_quant import _decode_tile, e8m0_to_f32
+    full = mxlib.FORMATS[fmt].full_grid().astype(np.float32)
+    codes = jnp.arange(len(full), dtype=jnp.int32)
+    b = jnp.arange(255, dtype=jnp.uint8)
+    got = _decode_tile(codes, fmt)[:, None] * e8m0_to_f32(b)[None, :]
+    want = (jnp.asarray(full)[:, None]
+            * packing.unpack_scales_e8m0(b)[None, :])
+    np.testing.assert_array_equal(np.asarray(got).view(np.uint32),
+                                  np.asarray(want).view(np.uint32))
+
+
+@pytest.mark.parametrize("fmt", FMTS)
+def test_packed_weight_tile_is_the_dequant_in_bf16(fmt):
+    """The GEMM's bf16 weight tile, built from each byte in place, is the
+    packing module's dequant cast to bf16, bit for bit and in K order:
+    every pair of codes in a byte, under every E8M0 byte the packer can
+    write (one column per scale byte)."""
+    from repro.kernels import packing
+    from repro.kernels.mx_matmul import _weight_tile_bf16
+    pairs = np.array([lo | (hi << 4) for hi in range(15)
+                      for lo in range(15)], np.uint8)       # 225 bytes
+    rows = 16 * 15                                          # 15 blocks
+    wp = jnp.asarray(np.resize(pairs, rows)[:, None].repeat(255, 1))
+    ws = jnp.asarray(np.arange(255, dtype=np.uint8)[None, :].repeat(15, 0))
+    got = jax.jit(lambda a, b: _weight_tile_bf16(a, b, fmt))(wp, ws)
+    want = packing.unpack_weight({"codes_packed": wp, "scales_e8m0": ws,
+                                  "fmt": fmt}).astype(jnp.bfloat16)
+    assert got.shape == want.shape == (2 * rows, 255)
+    np.testing.assert_array_equal(np.asarray(got).view(np.uint16),
+                                  np.asarray(want).view(np.uint16))
+
+
+@pytest.mark.parametrize("fmt", FMTS + ["mxfp8", "mxint8"])
+def test_quant_tile_snaps_like_encode_at_every_midpoint(fmt):
+    """The in-kernel snap gives the reference's codes and scales for
+    values on and 1-3 ulps either side of every midpoint, in blocks of
+    every scale exponent from 2^-100 up (where a scale that is not
+    exactly the power of two, as exp2 can give, moves a tie)."""
+    from repro.core import mx as mxlib
+    from repro.kernels.mx_quant import _format_consts, _quant_tile
+    grid, mids, r_max, center = _format_consts(fmt)
+    p = np.arange(-100, 126) - r_max
+    base = np.ldexp(mids[None, :].astype(np.float32), p[:, None])
+    vals = [base]
+    for side in (np.inf, -np.inf):
+        v = base
+        for _ in range(3):
+            v = np.nextafter(v, np.float32(side))
+            vals.append(v)
+    vals = np.stack(vals, -1).reshape(len(p), -1)         # (exps, n)
+    blk = np.zeros((len(p), vals.shape[1], 32), np.float32)
+    blk[..., 0] = np.ldexp(np.float32(grid[-1]), p)[:, None]  # sets e
+    blk[..., 1], blk[..., 2] = vals, -vals
+    x = jnp.asarray(blk.reshape(-1, 32))
+    codes, scale = _quant_tile(x[:, None, :], grid, mids, r_max, center)
+    codes_ref, scale_ref = mxlib.encode(
+        x, mxlib.MXConfig(fmt=fmt, block_size=32))
+    np.testing.assert_array_equal(np.asarray(codes[:, 0]),
+                                  np.asarray(codes_ref))
+    np.testing.assert_array_equal(np.asarray(scale[:, 0]),
+                                  np.asarray(scale_ref[:, 0]))
+
+
+def _on_grid_acts(m, k, fmt, rng):
+    """(m, k) activations on the MX grid: every 32-block holds +-max, so
+    its scale is exactly 2^p, with p in {-1, 0, 1}."""
+    from repro.core import mx as mxlib
+    el = mxlib.FORMATS[fmt]
+    q = rng.choice(el.full_grid(), size=(m, k))
+    q[:, ::32] = el.max_val * rng.choice([-1.0, 1.0], size=(m, k // 32))
+    p = rng.integers(-1, 2, size=(m, k // 32))
+    return (q * np.repeat(np.exp2(p), 32, axis=1)).astype(np.float32)
+
+
+@pytest.mark.parametrize("fmt", FMTS)
+@pytest.mark.parametrize("t3", [False, True])
+def test_mx_gemm_packed_exact_at_decode_shape(fmt, t3):
+    """At M = 64 the bf16-operand kernel equals its f32 oracle exactly
+    when every partial sum is exact in f32: activations on the grid and
+    weight scale bytes in 125..129. With t3 the activations are given
+    pre-rotated by H, so the in-kernel rotation lands back on the grid."""
+    from repro.core import transforms as tfm
+    m, k, n = 64, 1024, 512
+    rng = np.random.default_rng(21 + t3)
+    x = jnp.asarray(_on_grid_acts(m, k, fmt, rng))
+    if t3:
+        x = tfm.apply_blockwise(x, tfm.hadamard_matrix(32, jnp.float32))
+    codes = rng.integers(0, 15, size=(k, n), dtype=np.uint8)
+    wp = jnp.asarray(codes[0::2] | (codes[1::2] << 4))
+    ws = jnp.asarray(rng.integers(125, 130, size=(k // 32, n),
+                                  dtype=np.uint8))
+    y = ops.mx_gemm_packed(x, wp, ws, fmt, t3=t3, interpret=True)
+    yr = ops.mx_matmul_packed_ref(x, wp, ws, fmt, t3=t3)
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(yr))

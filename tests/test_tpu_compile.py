@@ -1,4 +1,5 @@
-"""The served Pallas kernels compile for a TPU v5e at TinyLlama-1.1B widths.
+"""The served Pallas kernels compile for a TPU v5e at TinyLlama-1.1B widths
+(and the packed GEMM at the widths the chip benchmark serves).
 
 Interpret mode (every other kernel test) never shows what Mosaic refuses:
 unsupported casts, blocks that break the (8, 128) tiling, reshapes of the
@@ -17,6 +18,7 @@ from repro.kernels import ops, packing
 
 D, D_FF, KV_DIM, H, DH = 2048, 5632, 256, 32, 64     # TinyLlama-1.1B
 BATCH = 4
+DS_D, DS_FF, DS_KV, DS_LANES = 8192, 22016, 1024, 64  # DeepSeek-67B, served
 
 
 @pytest.fixture(scope="module")
@@ -55,6 +57,9 @@ def _assert_mosaic(fn, *shapes):
     (BATCH, D_FF, D, True),        # decode rows, ffn_down (fused T3)
     (256, D, KV_DIM, False),       # prefill rows, k/v projection
     (256, D_FF, D, True),          # prefill rows, ffn_down (fused T3)
+    (DS_LANES, DS_D, DS_FF, False),    # the benchmark's decode, ffn_in
+    (DS_LANES, DS_FF, DS_D, True),     # the benchmark's decode, ffn_down
+    (256, DS_D, DS_KV, False),         # the benchmark's prefill, k/v
 ])
 def test_mx_gemm_packed_compiles(spec, m, k, n, t3):
     _assert_mosaic(
