@@ -66,6 +66,14 @@ class ElementFormat:
     bits: int
     grid: tuple  # positive half-grid including 0, ascending
     r_max: int   # max representable power-of-two exponent (for scale calc)
+    # The grid as a float format, for the closed-form encode
+    # (``_grid_index``): ``mantissa_bits`` M, and ``min_exponent`` the
+    # exponent of its lowest normal binade (1 - bias). Below
+    # 2^min_exponent the grid steps by 2^(min_exponent - M) (subnormals);
+    # each binade above holds 2^M values. An INT grid is all subnormals
+    # of step 1: M = bits - 1 = min_exponent.
+    mantissa_bits: int
+    min_exponent: int
 
     @property
     def max_val(self) -> float:
@@ -76,15 +84,18 @@ class ElementFormat:
         return np.concatenate([-pos[::-1][:-1], pos])
 
 
-FP4 = ElementFormat("fp4_e2m1", 4, tuple(_FP4_POS.tolist()), r_max=2)
-FP6 = ElementFormat("fp6_e2m3", 6, tuple(_FP6_POS.tolist()), r_max=2)
-FP8 = ElementFormat("fp8_e4m3", 8, tuple(_fp8_e4m3_grid().tolist()), r_max=8)
+FP4 = ElementFormat("fp4_e2m1", 4, tuple(_FP4_POS.tolist()), r_max=2,
+                    mantissa_bits=1, min_exponent=0)
+FP6 = ElementFormat("fp6_e2m3", 6, tuple(_FP6_POS.tolist()), r_max=2,
+                    mantissa_bits=3, min_exponent=0)
+FP8 = ElementFormat("fp8_e4m3", 8, tuple(_fp8_e4m3_grid().tolist()), r_max=8,
+                    mantissa_bits=3, min_exponent=-6)
 # INT4 symmetric: codes -7..7. r_max chosen so max code magnitude (7) sits
 # just inside [2^r_max, 2^(r_max+1)) => r_max = 2 (MR-GPTQ convention).
-INT4 = ElementFormat(
-    "int4", 4, tuple(np.arange(0.0, 8.0).tolist()), r_max=2
-)
-INT8 = ElementFormat("int8", 8, tuple(np.arange(0.0, 128.0).tolist()), r_max=6)
+INT4 = ElementFormat("int4", 4, tuple(np.arange(0.0, 8.0).tolist()), r_max=2,
+                     mantissa_bits=3, min_exponent=3)
+INT8 = ElementFormat("int8", 8, tuple(np.arange(0.0, 128.0).tolist()),
+                     r_max=6, mantissa_bits=7, min_exponent=7)
 
 FORMATS = {f.name: f for f in (FP4, FP6, FP8, INT4, INT8)}
 FORMATS.update({"mxfp4": FP4, "mxint4": INT4, "mxfp8": FP8, "mxfp6": FP6,
@@ -114,8 +125,9 @@ NVFP4 = MXConfig(fmt="mxfp4", block_size=16, scale_mode="fp8")
 
 
 # ---------------------------------------------------------------------------
-# Element quantizer Q_e — snap to nearest grid point (ties-to-even-ish via
-# midpoint comparison; the grids are tiny so a bucketize is exact & fast).
+# Element quantizer Q_e — snap to the nearest grid point by midpoint
+# comparison; a value exactly on a midpoint rounds up in magnitude (away
+# from zero), the rule every encoder and reference here shares.
 # ---------------------------------------------------------------------------
 
 def _snap_to_grid(x: jnp.ndarray, grid: np.ndarray) -> jnp.ndarray:
@@ -244,6 +256,33 @@ def blockwise_error(x: jnp.ndarray, q: jnp.ndarray, block_size: int) -> jnp.ndar
 # Packed-code path (used by kernels & serving): uint8 codes + fp32 scales
 # ---------------------------------------------------------------------------
 
+def _grid_index(mag: jnp.ndarray, element: ElementFormat) -> jnp.ndarray:
+    """Half-grid index of the grid point nearest each f32 magnitude, in
+    closed form: ``searchsorted(midpoints, mag, side="right")`` (a tie
+    rounds up) without the search loop or a table.
+
+    With E = max(exponent of mag, min_exponent), the grid steps by
+    q = 2^(E - M) around mag, and binade E starts at index
+    (E - min_exponent) * 2^M. x = mag * 2^(M - E) is exact (a power of two
+    built from bits), so rounding x half up gives the step; a carry past
+    2^(M+1) lands on the next binade's first index by itself, and
+    subnormals (E = min_exponent) need no case of their own. Round half
+    up as f + (x - f >= 0.5), f = floor(x): ``floor(x + 0.5)`` is wrong,
+    since 0.49999997 + 0.5 rounds to 1.0 in f32. Magnitudes past the top
+    (inf, NaN included) are first clamped to twice the largest grid value,
+    which keeps the arithmetic finite and saturates to the top index."""
+    m, e_min = element.mantissa_bits, element.min_exponent
+    cap = 2.0 * element.max_val
+    mag = jnp.where(mag <= cap, mag, cap)  # NaN compares false: the top
+    bits = jax.lax.bitcast_convert_type(mag, jnp.int32)
+    e = jnp.maximum((bits >> 23) - 127, e_min)
+    inv_q = jax.lax.bitcast_convert_type((m - e + 127) << 23, jnp.float32)
+    x = mag * inv_q
+    f = jnp.floor(x)
+    n = f.astype(jnp.int32) + (x - f >= 0.5).astype(jnp.int32)
+    return jnp.minimum(((e - e_min) << m) + n, len(element.grid) - 1)
+
+
 def encode(x: jnp.ndarray, cfg: MXConfig | None = None):
     """Quantize and return (codes uint8, scales fp32).
 
@@ -258,10 +297,8 @@ def encode(x: jnp.ndarray, cfg: MXConfig | None = None):
     z = (xb / scales[..., None].astype(x.dtype)).reshape(*lead, d)
     # magnitude-symmetric code (matches _snap_to_grid tie behaviour and the
     # Pallas kernels): code = center ± halfgrid_index(|z|)
-    g = jnp.asarray(cfg.element.grid, dtype=jnp.float32)
-    mids = (g[1:] + g[:-1]) / 2.0
     zf = z.astype(jnp.float32)
-    idx = jnp.searchsorted(mids, jnp.abs(zf), side="right")
+    idx = _grid_index(jnp.abs(zf), cfg.element)
     center = len(cfg.element.grid) - 1
     codes = center + jnp.where(zf < 0, -idx, idx)
     return codes.astype(jnp.uint8), scales

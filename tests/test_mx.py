@@ -93,3 +93,78 @@ def test_rtn_one_matrix_at_a_time_matches_whole_stack(shape):
                                         ste=False), -1, -2)
     assert got.shape == w.shape and got.dtype == w.dtype
     np.testing.assert_array_equal(np.asarray(got), np.asarray(whole))
+
+
+def _encode_searchsorted(x, cfg):
+    """Reference encode: the element index by a binary search of |z|
+    over the grid's midpoints."""
+    B = cfg.block_size
+    *lead, d = x.shape
+    scales = mxlib.compute_scales(x, cfg)
+    xb = x.reshape(*lead, d // B, B)
+    z = (xb / scales[..., None].astype(x.dtype)).reshape(*lead, d)
+    g = jnp.asarray(cfg.element.grid, dtype=jnp.float32)
+    mids = (g[1:] + g[:-1]) / 2.0
+    zf = z.astype(jnp.float32)
+    idx = jnp.searchsorted(mids, jnp.abs(zf), side="right")
+    center = len(cfg.element.grid) - 1
+    codes = center + jnp.where(zf < 0, -idx, idx)
+    return codes.astype(jnp.uint8), scales
+
+
+@pytest.mark.parametrize("fmt", ["mxfp4", "mxfp6", "mxfp8", "mxint4",
+                                 "mxint8"])
+def test_encode_matches_searchsorted_oracle(fmt):
+    """The closed-form grid index gives the oracle's codes and scales bit
+    for bit: on every grid value, every midpoint and its f32 neighbours,
+    the special values, and seeded values over the format's range."""
+    cfg = mxlib.MXConfig(fmt=fmt)
+    el = cfg.element
+    g = np.asarray(el.grid, np.float32)
+    mids = (g[1:] + g[:-1]) / 2
+    special = np.array([0.0, np.inf, np.nan, 1e-45, 1e-40, 1e30],
+                       np.float32)
+    exact = np.concatenate([g, mids, np.nextafter(mids, np.float32(np.inf)),
+                            np.nextafter(mids, np.float32(-np.inf)),
+                            special])
+    exact = np.concatenate([exact, -exact])
+
+    # the element index on raw magnitudes, past the top and NaN included
+    g32 = jnp.asarray(g)
+    want_idx = jnp.searchsorted((g32[1:] + g32[:-1]) / 2.0,
+                                jnp.abs(jnp.asarray(exact)), side="right")
+    got_idx = mxlib._grid_index(jnp.abs(jnp.asarray(exact)), el)
+    np.testing.assert_array_equal(np.asarray(got_idx), np.asarray(want_idx))
+    top = len(g) - 1
+    assert (np.asarray(got_idx)[np.abs(exact) > el.max_val] == top).all()
+    assert (np.asarray(got_idx)[np.isnan(exact)] == top).all()
+
+    # through encode: rows of 31 values and one 2^r_max, so that every
+    # finite in-range value sits at scale 1 where it was written
+    pad = (-exact.size) % 31
+    rows = np.concatenate([exact, np.zeros(pad, np.float32)]).reshape(-1, 31)
+    anchor = np.full((rows.shape[0], 1), 2.0 ** el.r_max, np.float32)
+    on_grid = np.concatenate([rows, anchor], axis=1)
+    rng = np.random.default_rng(17)
+    mag = np.exp2(rng.uniform(-12.0, np.log2(2 * el.max_val), 100_000))
+    spread = (mag * rng.choice([-1.0, 1.0], mag.size)).astype(np.float32)
+    for x in (on_grid, spread.reshape(-1, 32)):
+        x = jnp.asarray(x)
+        codes, scales = mxlib.encode(x, cfg)
+        want_codes, want_scales = _encode_searchsorted(x, cfg)
+        np.testing.assert_array_equal(np.asarray(codes),
+                                      np.asarray(want_codes))
+        np.testing.assert_array_equal(np.asarray(scales),
+                                      np.asarray(want_scales))
+
+
+def test_encode_element_constants_describe_the_grid():
+    """Each format's mantissa_bits / min_exponent rebuild its grid."""
+    for el in {id(f): f for f in mxlib.FORMATS.values()}.values():
+        m, e0 = el.mantissa_bits, el.min_exponent
+        step = 2.0 ** (e0 - m)
+        vals = [n * step for n in range(2 ** m)]
+        vals += [(2 ** m + n) * 2.0 ** (e - m)
+                 for e in range(e0, e0 + 16) for n in range(2 ** m)]
+        want = [v for v in vals if v <= el.max_val]
+        np.testing.assert_array_equal(np.asarray(el.grid), want)
